@@ -93,7 +93,7 @@ def main() -> None:
     if args.steps < 1:
         ap.error("--steps must be >= 1")
 
-    pallas_impl = dispatch.resolve_impl("pallas")  # "pallas" on TPU else interpreter
+    pallas_impl = "pallas" if dispatch.backend() == "tpu" else "interpret"
     shapes = SHAPES if pallas_impl == "pallas" else SHAPES_INTERPRET
     print(f"# backend={dispatch.backend()} fused_path={pallas_impl} "
           f"steps={args.steps}")

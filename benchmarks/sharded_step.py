@@ -1,7 +1,7 @@
 """ZeRO sharded-step benchmark (PR 9): what sharding the family-stacked
 projected state actually buys.
 
-Three measurements on the fused gum step over the llama-60m smoke model:
+Two counts on the fused gum step over the llama-60m smoke model:
 
   * per-device optimizer-state bytes vs mesh size (1/2/4/8) — the static
     accountant (:func:`repro.analysis.buffers.per_shard_memory` with
@@ -11,13 +11,13 @@ Three measurements on the fused gum step over the llama-60m smoke model:
   * refresh-boundary gather cost vs mesh size — count, per-shard payload
     and ring wire bytes of the cond-gated all_gathers, from the traced
     schedule (paid once per refresh period, zero in steady state).
-  * steady-step wall time, sharded vs replicated state, on a REAL host-CPU
-    mesh (subprocess per mesh so device forcing precedes jax init) — the
-    check that ZeRO sharding does not tax the steady path.
+
+Both are counts, not speeds.  The sharded-vs-replicated comparison on real
+devices is ``python chip_smoke.py --chips 4`` (four TPU chips, one process).
 
 Emits ``name,us_per_call,derived`` CSV rows and writes
 ``BENCH_sharded_step.json`` under --out (default results/).  ``--smoke``
-keeps one abstract mesh-8 row and skips the timed subprocesses + JSON.
+keeps one abstract mesh-8 row and skips the JSON.
 
 Usage: PYTHONPATH=src python benchmarks/sharded_step.py [--out DIR]
 """
@@ -26,57 +26,12 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
-import sys
 import time
 
 from repro.analysis.audit import audit_sharded
 from repro.core import OptimizerConfig
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MESHES = (1, 2, 4, 8)
-TIMED_MESHES = (1, 2)
-
-_TIMED_SCRIPT = """
-import json, sys, time
-from repro.launch.devices import force_host_device_count
-N = int(sys.argv[1])
-force_host_device_count(N)
-import jax, jax.numpy as jnp
-from repro.configs import get_smoke
-from repro.core import OptimizerConfig, build_optimizer
-from repro.launch.shardmap_fsdp import make_shardmap_train_step
-from repro.models import build_model
-
-cfg = get_smoke("llama-60m")
-model = build_model(cfg)
-params = model.init(jax.random.PRNGKey(0))
-tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 64), 0, cfg.vocab)
-batch = {"tokens": tokens}
-mesh = jax.make_mesh((N,), ("data",), devices=jax.devices()[:N])
-copy = lambda t: jax.tree_util.tree_map(jnp.copy, t)
-
-out = {}
-for shard_state in (True, False):
-    opt = build_optimizer(OptimizerConfig(
-        name="gum", lr=1e-2, rank=16, gamma=1, period=100, projector="svd",
-        fuse_families=True))
-    _, jit_builder = make_shardmap_train_step(
-        model, opt, mesh, grad_clip=1.0, shard_state=shard_state)
-    p, s = copy(params), opt.init(copy(params))
-    jitted = jit_builder(p, s)
-    p, s, m = jitted(p, s, batch)   # compile + the step-0 refresh
-    jax.block_until_ready(p)
-    t0 = time.time()
-    steps = 5
-    for _ in range(steps):          # period=100 -> pure steady state
-        p, s, m = jitted(p, s, batch)
-    jax.block_until_ready(p)
-    out["sharded" if shard_state else "replicated"] = (
-        (time.time() - t0) / steps * 1e6)
-print("TIMED_JSON " + json.dumps(out))
-"""
-
 
 def abstract_rows(smoke_mode: bool):
     """Per-mesh static rows: per-device state bytes + boundary schedule,
@@ -114,32 +69,6 @@ def abstract_rows(smoke_mode: bool):
     return rows
 
 
-def timed_rows():
-    """Steady-step wall time on real host-CPU meshes — one subprocess per
-    mesh so ``force_host_device_count`` precedes jax initialisation."""
-    rows = {}
-    env = {**os.environ, "PYTHONPATH": os.path.join(REPO, "src")}
-    for n in TIMED_MESHES:
-        t0 = time.time()
-        r = subprocess.run(
-            [sys.executable, "-c", _TIMED_SCRIPT, str(n)],
-            capture_output=True, text=True, env=env, cwd=REPO, timeout=600)
-        line = next((ln for ln in r.stdout.splitlines()
-                     if ln.startswith("TIMED_JSON ")), None)
-        if line is None:
-            print(f"sharded_step_time_mesh{n},0,"
-                  f"failed:{r.stderr.strip()[-200:]}", flush=True)
-            continue
-        row = json.loads(line[len("TIMED_JSON "):])
-        rows[f"mesh{n}"] = row
-        ratio = row["sharded"] / row["replicated"]
-        print(f"sharded_step_time_mesh{n},{row['sharded']:.0f},"
-              f"replicated_us={row['replicated']:.0f}"
-              f",sharded_over_replicated={ratio:.2f}"
-              f",subprocess_s={time.time() - t0:.0f}", flush=True)
-    return rows
-
-
 def main() -> None:
     from _smoke import smoke
 
@@ -150,10 +79,9 @@ def main() -> None:
     print("name,us_per_call,derived")
     state = abstract_rows(smoke())
     if smoke():
-        print("# smoke mode: skipping timed meshes and "
-              "BENCH_sharded_step.json write", flush=True)
+        print("# smoke mode: skipping BENCH_sharded_step.json write",
+              flush=True)
         return
-    times = timed_rows()
 
     # the claim the JSON records: shardable projected state scales ~1/N
     b1 = state["mesh1"]["opt_state_bytes_per_shard"]
@@ -164,7 +92,6 @@ def main() -> None:
         "model": "llama-60m (smoke)",
         "optimizer": "gum fused (rank=16, gamma=1)",
         "per_device_state": state,
-        "steady_step_us": times,
     }
     os.makedirs(args.out, exist_ok=True)
     path = os.path.join(args.out, "BENCH_sharded_step.json")

@@ -103,7 +103,11 @@ def collect_collectives(jaxpr) -> list[CollectiveRecord]:
 
     def walk(j, under_cond: bool, path: tuple[str, ...]) -> None:
         core = j.jaxpr if hasattr(j, "jaxpr") else j
-        producer: dict[int, Any] = {}
+        producer: dict[int, Any] = {}   # id(var) -> (primitive, id(eqn))
+        # A tree-level psum binds one equation per leaf (jax >= 0.9); the
+        # leaves of one barrier-pinned tree are one reduction, which XLA's
+        # all-reduce combiner sends as one wire operation: merge them.
+        tree_psums: dict[tuple, int] = {}
         for eqn in core.eqns:
             name = eqn.primitive.name
             if name in COLLECTIVE_PRIMS:
@@ -114,12 +118,10 @@ def collect_collectives(jaxpr) -> list[CollectiveRecord]:
                 payload = sum(
                     int(a.size) * a.dtype.itemsize for a in avals
                 )
+                sources = {producer.get(id(v), ("", None)) for v in eqn.invars}
                 pinned = bool(avals) and all(
-                    producer.get(id(v), "") == "optimization_barrier"
-                    for v in eqn.invars
-                )
-                launch_count.record(name)
-                records.append(CollectiveRecord(
+                    src == "optimization_barrier" for src, _ in sources)
+                rec = CollectiveRecord(
                     primitive=name,
                     axes=_eqn_axes(eqn),
                     dtypes=dtypes,
@@ -129,9 +131,26 @@ def collect_collectives(jaxpr) -> list[CollectiveRecord]:
                     under_cond=under_cond,
                     pinned=pinned,
                     path=path,
-                ))
+                )
+                key = (name, rec.axes, next(iter(sources))[1]) \
+                    if pinned and len(sources) == 1 else None
+                if key is not None and key in tree_psums:
+                    i = tree_psums[key]
+                    prev = records[i]
+                    records[i] = dataclasses.replace(
+                        prev,
+                        dtypes=tuple(sorted(set(prev.dtypes) | set(dtypes))),
+                        shapes=prev.shapes + shapes,
+                        n_operands=prev.n_operands + rec.n_operands,
+                        payload_bytes=prev.payload_bytes + payload,
+                    )
+                else:
+                    if key is not None:
+                        tree_psums[key] = len(records)
+                    launch_count.record(name)
+                    records.append(rec)
             for v in eqn.outvars:
-                producer[id(v)] = name
+                producer[id(v)] = (name, id(eqn))
             gated = under_cond or name in _GATED_PRIMS
             for val in eqn.params.values():
                 for sub in _subjaxprs(val):
@@ -445,7 +464,7 @@ def trace_sharded_step(model, optimizer: Transform, *, n_shards: int,
 
     from repro.launch.shardmap_fsdp import make_shardmap_train_step
 
-    mesh = AbstractMesh(((data_axis, int(n_shards)),))
+    mesh = AbstractMesh((int(n_shards),), (data_axis,))
     step, _ = make_shardmap_train_step(
         model, optimizer, mesh,
         grad_clip=grad_clip, reduce_dtype=reduce_dtype, data_axis=data_axis,

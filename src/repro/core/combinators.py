@@ -102,7 +102,6 @@ from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map as _shard_map
 from jax.sharding import PartitionSpec as _P
 
 from .api import (
@@ -996,9 +995,9 @@ def lowrank(
             k = jax.lax.axis_index(axis)
             return jax.lax.dynamic_slice_in_dim(p_full, k * loc, loc, axis=0)
 
-        return _shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=(_P(axis), _P()), out_specs=_P(axis),
-            check_rep=False,
+            check_vma=False,
         )(g_stack, keys_proj)
 
     def _refresh_projectors(fam, g_stack, keys_proj):

@@ -115,8 +115,8 @@ def lowrank_momentum_update(
     kernel_impl: str = "jnp",
 ) -> jax.Array:
     """The per-step hot loop ``R' = beta·R + coeff·⟨P, G⟩`` with kernel
-    dispatch: ``kernel_impl`` routes to the fused Pallas kernel (TPU, or the
-    interpreter off-TPU for "pallas"/"interpret") or the jnp einsum path
+    dispatch: ``kernel_impl`` routes to the fused Pallas kernel ("pallas" on
+    TPU, "interpret" in the interpreter anywhere) or the jnp einsum path
     ("jnp"; also what "auto" resolves to off-TPU).  All impls agree within
     fp32 roundoff; the jnp path is bit-identical to the pre-dispatch code."""
     from repro.kernels import dispatch  # lazy: kernels imports this module's peers

@@ -10,10 +10,9 @@ Implementation dispatch (the ``impl`` argument):
   * ``"auto"``            — :mod:`repro.kernels.dispatch` picks the fused
                             Pallas TPU kernels on TPU and this jnp path
                             elsewhere (shape-illegal inputs also fall back).
-  * ``"pallas"``          — the Pallas kernels; off-TPU this degrades to the
-                            Pallas interpreter so tests exercise the kernel
-                            code on any backend.
-  * ``"interpret"``       — the Pallas interpreter explicitly.
+  * ``"pallas"``          — the compiled Pallas kernels; raises off-TPU.
+  * ``"interpret"``       — the Pallas kernels in the interpreter, on any
+                            backend (what the CPU tests use).
 
 Key property for the paper (Lemma 1 / Property II):
 ``newton_schulz(P @ X) == P @ newton_schulz(X)`` whenever ``PᵀP = I`` —

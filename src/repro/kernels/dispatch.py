@@ -9,17 +9,17 @@ implementation actually runs:
 
   impl="auto"      — Pallas on TPU, the jnp reference elsewhere (default).
   impl="jnp"/"xla" — the pure-jnp reference path, everywhere.
-  impl="pallas"    — the Pallas kernel; off-TPU it degrades to the Pallas
-                     interpreter so the kernel code is still exercised
-                     (this is what CI parity tests rely on).
-  impl="interpret" — the Pallas interpreter explicitly.
+  impl="pallas"    — the compiled Pallas kernel; raises off-TPU.
+  impl="interpret" — the Pallas kernel in the interpreter, on any backend
+                     (what the CPU parity tests ask for).
 
 On top of backend selection the dispatchers add what the raw kernels
 deliberately do not have:
 
   * shape-legality checks — shapes whose VMEM working set cannot fit
-    (rank > MAX_LOWRANK_RANK, NS Gram side > MAX_NS_DIM) silently fall
-    back to the jnp reference instead of failing to compile;
+    (rank > MAX_LOWRANK_RANK, NS Gram side > MAX_NS_DIM) fall back to the
+    jnp reference instead of failing to compile, and each fallback is
+    recorded with its op and shape (``launch_count.count_fallbacks``);
   * padding-aware wrappers — ragged (non tile-divisible) ``(m, n)`` are
     zero-padded to legal tiles and the result sliced back, which is exact
     for both ops (zero rows/columns contribute nothing to PᵀG or X Xᵀ and
@@ -29,6 +29,10 @@ deliberately do not have:
     whole family is a single ``pallas_call``.  (The kernels carry their own
     batch grid axis rather than relying on ``jax.vmap``, whose batching
     rule would renumber the ``pl.program_id`` axes inside the kernels.)
+  * per-device calls under a mesh — GSPMD cannot partition a Pallas TPU
+    kernel (the compiler refuses it), so under a context mesh each call
+    runs through ``jax.shard_map`` on every device's share of the family
+    (:func:`_per_device`).
 
 ``KernelEntry``/``REGISTRY`` (re-exported as ``repro.kernels.KERNEL_REGISTRY``)
 name each dispatched op with its reference oracle and legality predicate, so
@@ -37,10 +41,13 @@ benchmarks and tests can enumerate the dispatch surface.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType, PartitionSpec as P
 
 from . import launch_count, ref
 from .fused_step import back_project_epilogue_batched
@@ -83,8 +90,8 @@ def backend() -> str:
 def resolve_impl(impl: str) -> str:
     """Normalize an impl request to one of {"jnp", "pallas", "interpret"}.
 
-    "auto" picks Pallas on TPU and jnp elsewhere; an explicit "pallas" off
-    TPU degrades to the interpreter so the kernel code still runs.
+    "auto" picks Pallas on TPU and jnp elsewhere.  An explicit "pallas" off
+    TPU raises: the interpreter is asked for by name, as "interpret".
     """
     if impl not in VALID_IMPLS:
         raise ValueError(f"impl must be one of {VALID_IMPLS}, got {impl!r}")
@@ -93,7 +100,20 @@ def resolve_impl(impl: str) -> str:
     if impl == "auto":
         return "pallas" if backend() == "tpu" else "jnp"
     if impl == "pallas" and backend() != "tpu":
-        return "interpret"
+        raise ValueError(
+            f"impl='pallas' compiles the kernels for a TPU, but the backend "
+            f"is {backend()!r}; ask for impl='interpret' to run them in the "
+            "Pallas interpreter")
+    return impl
+
+
+def _resolve_legal(impl: str, op: str, legal: bool, shape) -> str:
+    """resolve_impl plus the VMEM legality bound: an illegal shape runs the
+    jnp reference, and the fallback is recorded with its op and shape."""
+    impl = resolve_impl(impl)
+    if impl != "jnp" and not legal:
+        launch_count.record_fallback(op, tuple(int(d) for d in shape))
+        return "jnp"
     return impl
 
 
@@ -128,11 +148,36 @@ def _pad_axis(x: jax.Array, axis: int, new_dim: int) -> jax.Array:
 
 
 def _flatten_lead(x: jax.Array) -> jax.Array:
-    """(*lead, a, b) -> (L, a, b).  Pallas calls are per-device (they run
-    under shard_map / fully replicated optimizer math), so this reshape is
-    invisible to GSPMD — the no-lead-reshape rule in lowrank_common applies
+    """(*lead, a, b) -> (L, a, b).  Pallas calls are per-device
+    (:func:`_per_device`), so this reshape is outside what GSPMD partitions
+    inside the kernel — the no-lead-reshape rule in lowrank_common applies
     to the partitioned jnp path, not here."""
     return x.reshape((-1,) + x.shape[-2:])
+
+
+def _per_device(kernel: Callable, *operands: jax.Array) -> jax.Array:
+    """Call ``kernel`` on (L, a, b) family operands, once per device under
+    the context mesh (``jax.set_mesh``; the trainer enters it).
+
+    GSPMD cannot partition a Pallas TPU kernel, so with a mesh the call goes
+    through ``jax.shard_map`` over the mesh's non-manual axes: the leading
+    family axis of every 3-D operand and of the result splits over them when
+    L divides their size, and is replicated otherwise (each device then
+    computes the whole family).  Other operands are replicated.  Without a
+    mesh, or inside a shard_map over all of it, the kernel is called as is.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    axes = tuple(a for a, t in zip(mesh.axis_names, mesh.axis_types)
+                 if t != AxisType.Manual and mesh.shape[a] > 1)
+    if not axes:
+        return kernel(*operands)
+    n = math.prod(mesh.shape[a] for a in axes)
+    spec = P(axes) if operands[0].shape[0] % n == 0 else P()
+    return jax.shard_map(
+        kernel, mesh=mesh, axis_names=set(axes), check_vma=False,
+        in_specs=tuple(spec if x.ndim == 3 else P() for x in operands),
+        out_specs=spec,
+    )(*operands)
 
 
 # --------------------------------------------------------------------------
@@ -205,9 +250,8 @@ def lowrank_update(
     Returns fp32, identical (within fp32 roundoff) across impls.
     ``pad_rank_to`` opts into lane-aligned rank padding (see _rank_granule).
     """
-    impl = resolve_impl(impl)
-    if impl != "jnp" and not lowrank_update_supported(p, g, side):
-        impl = "jnp"
+    impl = _resolve_legal(impl, "lowrank_update",
+                          lowrank_update_supported(p, g, side), g.shape)
     launch_count.record("lowrank_update")
     if impl == "jnp":
         return beta * r_state.astype(jnp.float32) + coeff * _project_jnp(p, g, side)
@@ -215,10 +259,9 @@ def lowrank_update(
     pk, gk, rk, (lead, r, n, bm, bn) = _lowrank_kernel_form(
         p, g, r_state, side, pad_rank_to
     )
-    out = lowrank_update_batched(
-        pk, gk, rk, beta, coeff, block_m=bm, block_n=bn,
-        interpret=(impl == "interpret"),
-    )
+    out = _per_device(functools.partial(
+        lowrank_update_batched, beta=beta, coeff=coeff, block_m=bm,
+        block_n=bn, interpret=(impl == "interpret")), pk, gk, rk)
     return _lowrank_unkernel_form(out, lead, r, n, side)
 
 
@@ -227,9 +270,8 @@ def project(p: jax.Array, g: jax.Array, *, side: str = "left",
     """Plain low-rank projection PᵀG / G P through the projection kernel —
     the dispatched counterpart of ``lowrank_common.project`` (used by the
     Adam-based optimizers, which consume the projected gradient itself)."""
-    impl = resolve_impl(impl)
-    if impl != "jnp" and not lowrank_update_supported(p, g, side):
-        impl = "jnp"
+    impl = _resolve_legal(impl, "project",
+                          lowrank_update_supported(p, g, side), g.shape)
     launch_count.record("project")
     if impl == "jnp":
         return _project_jnp(p, g, side)
@@ -237,9 +279,9 @@ def project(p: jax.Array, g: jax.Array, *, side: str = "left",
     pk, gk, _, (lead, r, n, bm, bn) = _lowrank_kernel_form(
         p, g, None, side, pad_rank_to
     )
-    out = project_batched(
-        pk, gk, 1.0, block_m=bm, block_n=bn, interpret=(impl == "interpret")
-    )
+    out = _per_device(functools.partial(
+        project_batched, coeff=1.0, block_m=bm, block_n=bn,
+        interpret=(impl == "interpret")), pk, gk)
     return _lowrank_unkernel_form(out, lead, r, n, side)
 
 
@@ -300,9 +342,8 @@ def back_project(p: jax.Array, s: jax.Array, *, side: str = "left",
     left  side: p (*lead, m, r), s (*lead, r, n) -> P @ S
     right side: p (*lead, n, r), s (*lead, m, r) -> S @ Pᵀ
     """
-    impl = resolve_impl(impl)
-    if impl != "jnp" and not back_project_supported(p, s, side):
-        impl = "jnp"
+    impl = _resolve_legal(impl, "back_project",
+                          back_project_supported(p, s, side), s.shape)
     launch_count.record("back_project")
     if impl == "jnp":
         return _back_project_jnp(p, s, side)
@@ -310,9 +351,9 @@ def back_project(p: jax.Array, s: jax.Array, *, side: str = "left",
     pk, sk, _, (lead, m, n, bm, bn) = _back_project_kernel_form(
         p, s, None, side, pad_rank_to
     )
-    out = back_project_batched(
-        pk, sk, block_m=bm, block_n=bn, interpret=(impl == "interpret")
-    )
+    out = _per_device(functools.partial(
+        back_project_batched, block_m=bm, block_n=bn,
+        interpret=(impl == "interpret")), pk, sk)
     return _back_project_unkernel_form(out, lead, m, n, side)
 
 
@@ -338,9 +379,8 @@ def back_project_epilogue(
     left  side: p (*lead, m, r), s (*lead, r, n), w (*lead, m, n)
     right side: p (*lead, n, r), s (*lead, m, r), w (*lead, m, n)
     """
-    impl = resolve_impl(impl)
-    if impl != "jnp" and not back_project_supported(p, s, side):
-        impl = "jnp"
+    impl = _resolve_legal(impl, "back_project_epilogue",
+                          back_project_supported(p, s, side), s.shape)
     launch_count.record("back_project_epilogue")
     if impl == "jnp":
         out = scale * _back_project_jnp(p, s, side)
@@ -353,10 +393,12 @@ def back_project_epilogue(
     )
     sd = jnp.stack([jnp.asarray(scale, jnp.float32),
                     jnp.asarray(decay, jnp.float32)]).reshape(1, 2)
-    out = back_project_epilogue_batched(
-        pk, sk, wk, sd, block_m=bm, block_n=bn,
-        interpret=(impl == "interpret"),
-    )
+    kernel = functools.partial(back_project_epilogue_batched, block_m=bm,
+                               block_n=bn, interpret=(impl == "interpret"))
+    if wk is None:
+        out = _per_device(lambda p, s, sd: kernel(p, s, None, sd), pk, sk, sd)
+    else:
+        out = _per_device(kernel, pk, sk, wk, sd)
     return _back_project_unkernel_form(out, lead, m, n, side)
 
 
@@ -370,6 +412,19 @@ def newton_schulz_supported(x: jax.Array) -> bool:
     return min(int(x.shape[-2]), int(x.shape[-1])) <= MAX_NS_DIM
 
 
+# Scoped VMEM the NS kernels plan for: the TPU compiler's default scoped limit
+# is 16 MiB, and it refuses m=1024 at block_n=512 (16.31 MiB on v5e).
+_NS_VMEM_BUDGET = 14 * 2**20
+
+
+def _ns_block_target(m: int, block_n: int) -> int:
+    """Cap the NS column tile so the kernels fit ``_NS_VMEM_BUDGET``: each
+    keeps one (m, m) fp32 block and two (m, block_n) fp32 tiles resident,
+    all double-buffered."""
+    cap = (_NS_VMEM_BUDGET - 2 * 4 * m * m) // (2 * 2 * 4 * m)
+    return max(_LANE, min(block_n, cap // _LANE * _LANE))
+
+
 def newton_schulz(
     x: jax.Array, *, steps: int = 5, eps: float = 1e-7, impl: str = "auto",
     block_n: int = 512,
@@ -378,9 +433,8 @@ def newton_schulz(
     :func:`repro.core.newton_schulz.newton_schulz` semantics."""
     from repro.core.newton_schulz import newton_schulz as ns_jnp
 
-    impl = resolve_impl(impl)
-    if impl != "jnp" and not newton_schulz_supported(x):
-        impl = "jnp"
+    impl = _resolve_legal(impl, "newton_schulz", newton_schulz_supported(x),
+                          x.shape)
     if impl == "jnp":
         # ns_jnp records the launch itself (jnp body), so don't double count.
         return ns_jnp(x, steps=steps, eps=eps)
@@ -398,12 +452,12 @@ def newton_schulz(
     # through every iteration (Gram gains zero blocks; a·X + A2·X preserves
     # them), and the Frobenius norm used for the initial scaling is unchanged.
     m_pad = _round_up(m, _SUBLANE)
-    n_pad, bn = _pad_and_block(n, block_n, _LANE)
+    n_pad, bn = _pad_and_block(n, _ns_block_target(m_pad, block_n), _LANE)
     xk = _flatten_lead(_pad_axis(_pad_axis(x, -2, m_pad), -1, n_pad))
 
-    out = newton_schulz_pallas(
-        xk, steps=steps, eps=eps, block_n=bn, interpret=interpret
-    )[..., :m, :n]
+    out = _per_device(functools.partial(
+        newton_schulz_pallas, steps=steps, eps=eps, block_n=bn,
+        interpret=interpret), xk)[..., :m, :n]
     out = out.reshape(lead + (m, n))
     if transposed:
         out = jnp.swapaxes(out, -1, -2)

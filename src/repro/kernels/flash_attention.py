@@ -23,15 +23,6 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _compiler_params():
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
-        )
-    except Exception:  # older/newer API drift — semantics are an optimization
-        return None
-
-
 def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     *, scale: float, causal: bool, block_q: int, block_kv: int, seq_q: int, seq_kv: int,
@@ -127,5 +118,7 @@ def flash_attention(
             pltpu.VMEM((block_q, D), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
-        compiler_params=_compiler_params(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
+        ),
     )(q, k, v)

@@ -58,12 +58,32 @@ COLLECTIVE_OPS = (
 _KNOWN_OPS = DISPATCH_OPS + COLLECTIVE_OPS
 
 _ACTIVE: list[dict[str, int]] = []
+_FALLBACKS: list[list[tuple[str, tuple[int, ...]]]] = []
 
 
 def record(op: str) -> None:
     """Count one launch of ``op`` in every active counter (no-op otherwise)."""
     for counts in _ACTIVE:
         counts[op] = counts.get(op, 0) + 1
+
+
+def record_fallback(op: str, shape: tuple[int, ...]) -> None:
+    """Note that a Pallas request for ``op`` at ``shape`` ran the jnp
+    reference because the shape breaks a VMEM bound (no-op outside
+    :func:`count_fallbacks`)."""
+    for log in _FALLBACKS:
+        log.append((op, shape))
+
+
+@contextlib.contextmanager
+def count_fallbacks() -> Iterator[list[tuple[str, tuple[int, ...]]]]:
+    """Collect every legality fallback traced in the body as ``(op, shape)``."""
+    log: list[tuple[str, tuple[int, ...]]] = []
+    _FALLBACKS.append(log)
+    try:
+        yield log
+    finally:
+        _FALLBACKS.remove(log)
 
 
 @contextlib.contextmanager

@@ -70,8 +70,8 @@ def default_optimizer(arch: str, kernel_impl: str = "auto",
                       telemetry: bool = False) -> OptimizerConfig:
     # GUM (the paper's method) with the TPU-native subspace projector.
     # kernel_impl is threaded into the compiled cell so dry runs lower the
-    # SAME hot path as training ("pallas" forces the fused kernels into the
-    # HLO even on the host-CPU placeholder devices); the fusion knobs do the
+    # SAME hot path as training ("interpret" puts the kernel code into the
+    # HLO on the host-CPU placeholder devices); the fusion knobs do the
     # same for the family-stacked engine; a rank policy lowers the cell at
     # the policy's INITIAL RankMap (rank changes re-lower per ladder rank).
     return OptimizerConfig(

@@ -9,6 +9,16 @@ axis carries only data parallelism + FSDP (cheap DCN-friendly collectives),
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes: the repo's sharding helpers
+    (``sharding.shard``'s ``with_sharding_constraint``, GSPMD in_shardings)
+    are written for Auto axes, and ``jax.make_mesh`` defaults to Explicit."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -23,7 +33,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {n} devices, found {len(devices)} — run under "
             "dryrun.py (it sets --xla_force_host_platform_device_count=512)"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return make_mesh(shape, axes, devices=devices[:n])
 
 
 def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
@@ -31,7 +41,7 @@ def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
     n = 1
     for s in shape:
         n *= s
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return make_mesh(shape, axes, devices=jax.devices()[:n])
 
 
 def make_data_mesh(n_shards: int, axis: str = "data"):
@@ -44,4 +54,4 @@ def make_data_mesh(n_shards: int, axis: str = "data"):
             f"data mesh needs {n_shards} devices, found {len(devices)} — "
             "call launch.devices.force_host_device_count first"
         )
-    return jax.make_mesh((n_shards,), (axis,), devices=devices[:n_shards])
+    return make_mesh((n_shards,), (axis,), devices=devices[:n_shards])

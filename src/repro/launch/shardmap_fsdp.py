@@ -26,7 +26,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.api import Transform, apply_updates, clip_by_global_norm, global_norm
@@ -92,12 +91,12 @@ def make_shardmap_train_step(
     replicated = P()
     batch_spec = {"tokens": P(data_axis)}
 
-    sharded_grad = shard_map(
+    sharded_grad = jax.shard_map(
         grad_body,
         mesh=mesh,
         in_specs=(replicated, batch_spec),
         out_specs=(replicated, replicated),
-        check_rep=False,
+        check_vma=False,
     )
 
     def train_step(params, opt_state, batch):

@@ -1,23 +1,36 @@
-"""Training launcher: ``python -m repro.launch.train --arch llama-130m ...``
+"""Training launcher: ``python -m repro.launch.train --arch llama-350m ...``
 
-Runs a real training loop on whatever devices exist (CPU here, TPU pod in
-production — the mesh flag switches pjit on).  For the production meshes use
-dryrun.py first to verify the cell compiles and fits.
+Runs a real training loop on the devices JAX finds.  On a TPU the optimizer
+hot loops run the Pallas kernels (``--kernel-impl auto``); with
+``JAX_PLATFORMS=cpu`` (the test suite) they run the jnp reference, and
+``--kernel-impl interpret`` runs the Pallas kernels in the interpreter.
+``python chip_smoke.py`` at the repo root drives this entry point in process
+on one chip (``--chips 4`` for the four-chip mesh phase).
+
+``main(argv)`` is callable in process and returns ``(trainer, result)``.
+It is ``run(*build_trainer(argv))``: ``build_trainer`` stops before the
+first step, so a caller can compile the step ahead of ``run``.  Outside the
+CPU it points JAX's persistent compilation cache at
+``JAX_COMPILATION_CACHE_DIR`` or ``<checkout>/.jax_cache``
+(:func:`repro.launch.devices.enable_compile_cache`).
 
 ``--audit`` runs the full static audit before step 0 (chain lint, launch
 model, dtype flow, recompile hazards, and — when ``--mesh`` is set — the
 sharded collective-schedule and donation/buffer passes) and exits non-zero
 on any error finding, so a misconfigured launch dies before it burns a
-single step.  ``--mesh data=8`` trains pjit'ed over a data mesh, forcing
-host CPU devices when the backend has fewer.
+single step.  ``--mesh data=4`` trains over a data mesh of the real devices;
+under ``JAX_PLATFORMS=cpu`` it forces that many host CPU devices.
 """
 from __future__ import annotations
 
 import argparse
+import math
 import sys
 
 
-def main():
+def build_trainer(argv=None):
+    """Parse ``argv`` and build the run's :class:`~repro.train.Trainer`
+    (running ``--audit`` first); returns ``(trainer, args)``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true", help="use the reduced config")
@@ -70,9 +83,9 @@ def main():
                          "e.g. 32,64,128 (bounds recompilation; empty = "
                          "powers of two up to --rank)")
     ap.add_argument("--mesh", default="", metavar="AXIS=N",
-                    help="train pjit'ed over a data mesh, e.g. data=8 "
-                         "(forces host CPU devices when the backend has "
-                         "fewer; production passes the real device mesh)")
+                    help="train over a data mesh of the real devices, e.g. "
+                         "data=4 on a four-chip host (with JAX_PLATFORMS=cpu "
+                         "that many host CPU devices are forced)")
     ap.add_argument("--resilience", nargs="?", const="", default=None,
                     metavar="SPEC",
                     help="turn on the health monitor + recovery ladder "
@@ -111,21 +124,26 @@ def main():
                          "collective/buffer passes when --mesh is set — "
                          "before step 0, exiting non-zero on any error "
                          "finding (parity with dryrun.py --audit)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+
+    from repro.launch.devices import (
+        cpu_requested,
+        enable_compile_cache,
+        force_host_device_count,
+    )
 
     # device forcing must precede the first jax backend use below
     mesh_axes = None
     if args.mesh:
         from repro.analysis.audit import _parse_mesh
-        from repro.launch.devices import force_host_device_count
 
         mesh_axes = _parse_mesh(args.mesh)
-        total = 1
-        for _, size in mesh_axes:
-            total *= size
-        force_host_device_count(total)
+        if cpu_requested():
+            force_host_device_count(math.prod(size for _, size in mesh_axes))
 
     import jax
+
+    enable_compile_cache()
 
     from repro.configs import RunConfig, get_config, get_smoke
     from repro.core import OptimizerConfig
@@ -157,9 +175,16 @@ def main():
 
     mesh = None
     if mesh_axes is not None:
+        from repro.launch.mesh import make_mesh
+
         sizes = tuple(size for _, size in mesh_axes)
         names = tuple(axis for axis, _ in mesh_axes)
-        mesh = jax.make_mesh(sizes, names)
+        n = math.prod(sizes)
+        if n > jax.device_count():
+            raise SystemExit(f"--mesh {args.mesh} needs {n} devices, found "
+                             f"{jax.device_count()} (with JAX_PLATFORMS=cpu "
+                             "that many host CPU devices are forced)")
+        mesh = make_mesh(sizes, names, devices=jax.devices()[:n])
 
     if args.audit:
         # The full static audit of exactly what is about to train, before
@@ -195,6 +220,11 @@ def main():
                       resilience=args.resilience, inject=inject,
                       telemetry=args.telemetry, events_out=args.events_out,
                       profile_steps=args.profile_steps)
+    return trainer, args
+
+
+def run(trainer, args):
+    """Train the run ``build_trainer`` built; returns its TrainResult."""
     result = trainer.train()
     print(
         f"done: step={result.final_step} "
@@ -212,6 +242,12 @@ def main():
         # sink handles remain, and process exit covers those.
         print(f"telemetry: {result.events_path} "
               f"(python -m repro.telemetry.report {args.ckpt_dir})")
+    return result
+
+
+def main(argv=None):
+    trainer, args = build_trainer(argv)
+    return trainer, run(trainer, args)
 
 
 if __name__ == "__main__":

@@ -29,12 +29,13 @@ def _mesh() -> Optional[Mesh]:
 
 @contextlib.contextmanager
 def use_mesh(mesh: Optional[Mesh]):
-    """Activate a mesh for logical-axis resolution (and pjit contexts)."""
+    """Activate a mesh for logical-axis resolution and as JAX's context mesh
+    (``jax.set_mesh``), which the kernel dispatcher reads at trace time."""
     prev = _mesh()
     _state.mesh = mesh
     try:
         if mesh is not None:
-            with mesh:
+            with jax.set_mesh(mesh):
                 yield mesh
         else:
             yield None

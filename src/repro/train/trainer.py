@@ -87,6 +87,9 @@ class TrainResult:
     fault_log: list = dataclasses.field(default_factory=list)
     # Path of the run's events.jsonl (None when telemetry is off).
     events_path: Optional[str] = None
+    # Wall seconds of each step run, device work included (the first holds
+    # the step's trace and compile).
+    step_times: list = dataclasses.field(default_factory=list)
 
 
 class Trainer:
@@ -227,6 +230,7 @@ class Trainer:
                 )
                 optimizer = self.rank_ctrl.transform()
         self._jit_cache: dict = {}
+        self._last_step_args = None  # for lower_step() after a run
         self._has_probes: Optional[bool] = None
         self._set_optimizer(
             optimizer if optimizer is not None else build_optimizer(opt_cfg)
@@ -259,8 +263,17 @@ class Trainer:
 
     def init_state(self):
         key = jax.random.PRNGKey(self.run.seed)
-        params = self.model.init(key)
-        opt_state = self.optimizer.init(params)
+        if self.mesh is None:
+            params = self.model.init(key)
+            return params, self.optimizer.init(params)
+        # On a mesh, each device builds only its own shards: eager init
+        # would first place the whole model and state on device 0.
+        params_abs = jax.eval_shape(self.model.init, key)
+        params = jax.jit(self.model.init, out_shardings=named_sharding_tree(
+            params_abs, self.mesh))(key)
+        opt_abs = jax.eval_shape(self.optimizer.init, params_abs)
+        opt_state = jax.jit(self.optimizer.init, out_shardings=opt_state_sharding(
+            opt_abs, self.mesh, family_axis=self._family_axis))(params)
         return params, opt_state
 
     def _jit_step(self, params, opt_state):
@@ -285,6 +298,37 @@ class Trainer:
             )
         self._jit_cache[key] = jitted
         return jitted
+
+    def lower_step(self, params=None, opt_state=None):
+        """Lower the current jitted step on abstract arguments.
+
+        Without arguments they are shaped like those of the last step the
+        run took, so ``.compile()`` returns the run's executable from JAX's
+        in-memory cache and its HLO and memory can be read.  With ``params``
+        (abstract or concrete) they are shaped like ``params``, ``opt_state``
+        (default: its abstract init) and one batch.  Committed arrays keep
+        their shardings: on what :meth:`init_state` returns, this is the
+        program the run's first step will find compiled."""
+        if params is None:
+            args = self._last_step_args
+        else:
+            from repro.resilience.inject import FaultGate
+
+            if opt_state is None:
+                opt_state = jax.eval_shape(self.optimizer.init, params)
+            batch = {"tokens": jax.ShapeDtypeStruct(
+                (self.data_cfg.global_batch // max(self.data_cfg.num_hosts, 1),
+                 self.data_cfg.seq_len), jnp.int32)}
+            args = (params, opt_state, batch)
+            if self._fault_gate is not None:
+                args = args + (FaultGate.disarmed(),)
+        args = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, weak_type=x.weak_type,
+                sharding=x.sharding if x.committed else None)
+            if isinstance(x, jax.Array) else x, args)
+        with use_mesh(self.mesh):  # the context the run's calls trace in
+            return self._jit_step(*args[:2]).lower(*args)
 
     # ------------------------------------------------------------- helpers
 
@@ -467,16 +511,7 @@ class Trainer:
                 from repro.analysis import donation_findings, parse_main_args
 
                 opt_state0 = jax.eval_shape(self.optimizer.init, params)
-                batch0 = {"tokens": jax.ShapeDtypeStruct(
-                    (self.data_cfg.global_batch
-                     // max(self.data_cfg.num_hosts, 1),
-                     self.data_cfg.seq_len), jnp.int32)}
-                args = (params, opt_state0, batch0)
-                if self._fault_gate is not None:
-                    args = args + (FaultGate.disarmed(),)
-                infos = parse_main_args(
-                    self._jit_step(params, opt_state0)
-                    .lower(*args).as_text())
+                infos = parse_main_args(self.lower_step(params).as_text())
                 n_donate = (len(jax.tree_util.tree_leaves(params))
                             + len(jax.tree_util.tree_leaves(opt_state0)))
                 self.tele.event(
@@ -504,6 +539,7 @@ class Trainer:
         step_jit = self._jit_step(params, opt_state)
 
         loss_by_step: dict[int, float] = {}
+        step_times: list[float] = []
         skipped = 0
         step = start_step
         tele, tcfg = self.tele, self.tele_cfg
@@ -546,13 +582,12 @@ class Trainer:
                                    step=step, severity="warn", kind=ev.kind)
                     fault = (FaultGate.armed(ev) if ev is not None
                              else FaultGate.disarmed())
-                    new_params, new_opt, metrics = step_jit(
-                        params, opt_state, {"tokens": tokens}, fault
-                    )
+                    args = (params, opt_state, {"tokens": tokens}, fault)
                 else:
-                    new_params, new_opt, metrics = step_jit(
-                        params, opt_state, {"tokens": tokens}
-                    )
+                    args = (params, opt_state, {"tokens": tokens})
+                self._last_step_args = args
+                new_params, new_opt, metrics = step_jit(*args)
+                jax.block_until_ready((new_params, new_opt))
                 loss = float(metrics["loss"])
                 params, opt_state = new_params, new_opt
                 applied = bool(metrics["update_applied"])
@@ -562,6 +597,7 @@ class Trainer:
                     # the step itself zeroed the update (in-jit NaN guard)
                     skipped += 1
                 dt = time.time() - t0
+                step_times.append(dt)
                 refresh_step = (self.opt_cfg.period > 0
                                 and step % self.opt_cfg.period == 0)
                 tele.record_span(
@@ -694,4 +730,5 @@ class Trainer:
             recovery_trace=list(recov.trace) if recov is not None else [],
             fault_log=list(plan.log) if plan is not None else [],
             events_path=self.events_path,
+            step_times=step_times,
         )

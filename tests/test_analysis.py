@@ -199,7 +199,7 @@ def _elementwise_transform(fn):
 
 def test_ra201_f64_leak():
     t = _elementwise_transform(lambda x: x.astype(jnp.float64))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         params = {"w": jax.ShapeDtypeStruct((8, 8), jnp.float32)}
         jaxpr, _ = trace_update(t, params)
         fs = dtype_flow_findings(jaxpr)

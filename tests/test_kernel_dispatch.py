@@ -1,6 +1,6 @@
 """The kernel dispatch subsystem: impl resolution, registry, padding-aware
 ragged-shape parity (Pallas interpret vs jnp reference), optimizer-level
-parity with kernel_impl="pallas", and the use_muon_scale wiring.
+parity with kernel_impl="interpret", and the use_muon_scale wiring.
 
 Everything runs the Pallas kernels through the interpreter (CPU), so the
 kernel code itself is exercised on every backend."""
@@ -25,15 +25,24 @@ KEY = jax.random.PRNGKey(0)
 
 
 def test_resolve_impl():
-    # CPU/GPU CI: auto -> jnp, pallas degrades to interpret.
+    # CPU/GPU CI: auto -> jnp; the interpreter is asked for by name.
     on_tpu = dispatch.backend() == "tpu"
     assert dispatch.resolve_impl("auto") == ("pallas" if on_tpu else "jnp")
-    assert dispatch.resolve_impl("pallas") == ("pallas" if on_tpu else "interpret")
     assert dispatch.resolve_impl("xla") == "jnp"
     assert dispatch.resolve_impl("jnp") == "jnp"
     assert dispatch.resolve_impl("interpret") == "interpret"
     with pytest.raises(ValueError):
         dispatch.resolve_impl("cuda")
+
+
+def test_resolve_pallas_raises_off_tpu(monkeypatch):
+    """An explicit "pallas" never degrades to the interpreter: off-TPU it
+    raises and names the backend it found."""
+    monkeypatch.setattr(dispatch, "backend", lambda: "cpu")
+    with pytest.raises(ValueError, match="'cpu'.*interpret"):
+        dispatch.resolve_impl("pallas")
+    monkeypatch.setattr(dispatch, "backend", lambda: "tpu")
+    assert dispatch.resolve_impl("pallas") == "pallas"
 
 
 def test_registry():
@@ -47,14 +56,22 @@ def test_registry():
 
 
 def test_shape_legality_fallback():
-    # rank beyond the VMEM bound must fall back to jnp, not fail to compile
+    # rank beyond the VMEM bound must fall back to jnp, not fail to compile,
+    # and the fallback is recorded with its op and shape
+    from repro.kernels import launch_count
+
     m, n, r = 8, 16, dispatch.MAX_LOWRANK_RANK + 1
     p = jnp.zeros((m, r))
     g = jnp.zeros((m, n))
     assert not dispatch.lowrank_update_supported(p, g, "left")
-    out = dispatch.lowrank_update(p, g, jnp.zeros((r, n)), 0.9, 1.0,
-                                  impl="interpret")
+    with launch_count.count_fallbacks() as fell_back:
+        out = dispatch.lowrank_update(p, g, jnp.zeros((r, n)), 0.9, 1.0,
+                                      impl="interpret")
+        dispatch.lowrank_update(p[:, :4], g, jnp.zeros((4, n)), 0.9, 1.0,
+                                impl="interpret")
+        dispatch.lowrank_update(p, g, jnp.zeros((r, n)), 0.9, 1.0, impl="jnp")
     assert out.shape == (r, n)
+    assert fell_back == [("lowrank_update", (m, n))]
     big = jnp.zeros((dispatch.MAX_NS_DIM + 8, dispatch.MAX_NS_DIM + 8))
     assert not dispatch.newton_schulz_supported(big)
 
@@ -180,7 +197,7 @@ def test_pad_rank_to_optimizer_parity():
     mk = lambda **kw: galore_matrices(1e-2, rank=6, period=3, base="muon",
                                       seed=2, **kw)
     p_ref = _run_traj(mk(kernel_impl="jnp"), params)
-    p_pad = _run_traj(mk(kernel_impl="pallas", pad_rank_to=128), params)
+    p_pad = _run_traj(mk(kernel_impl="interpret", pad_rank_to=128), params)
     for a, b in zip(jax.tree_util.tree_leaves(p_ref),
                     jax.tree_util.tree_leaves(p_pad)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -238,12 +255,12 @@ PARAMS = {
 
 
 def test_gum_kernel_impl_pallas_matches_jnp():
-    """Acceptance: gum_matrices(kernel_impl="pallas") (interpret on CPU)
+    """Acceptance: gum_matrices(kernel_impl="interpret") (the Pallas kernels)
     matches the jnp path within fp32 tolerance, across a projector refresh."""
     mk = lambda impl: gum_matrices(1e-2, rank=6, gamma=1, period=3,
                                    projector="svd", seed=5, kernel_impl=impl)
     p_jnp = _run_traj(mk("jnp"), PARAMS)
-    p_pal = _run_traj(mk("pallas"), PARAMS)
+    p_pal = _run_traj(mk("interpret"), PARAMS)
     for a, b in zip(jax.tree_util.tree_leaves(p_jnp),
                     jax.tree_util.tree_leaves(p_pal)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -255,7 +272,7 @@ def test_galore_kernel_impl_pallas_matches_jnp(base):
     mk = lambda impl: galore_matrices(1e-2, rank=6, period=3, projector="svd",
                                       base=base, seed=2, kernel_impl=impl)
     p_jnp = _run_traj(mk("jnp"), PARAMS)
-    p_pal = _run_traj(mk("pallas"), PARAMS)
+    p_pal = _run_traj(mk("interpret"), PARAMS)
     for a, b in zip(jax.tree_util.tree_leaves(p_jnp),
                     jax.tree_util.tree_leaves(p_pal)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -265,7 +282,7 @@ def test_galore_kernel_impl_pallas_matches_jnp(base):
 def test_muon_kernel_impl_pallas_matches_jnp():
     mk = lambda impl: muon_matrices(1e-2, kernel_impl=impl)
     p_jnp = _run_traj(mk("jnp"), PARAMS, steps=3)
-    p_pal = _run_traj(mk("pallas"), PARAMS, steps=3)
+    p_pal = _run_traj(mk("interpret"), PARAMS, steps=3)
     for a, b in zip(jax.tree_util.tree_leaves(p_jnp),
                     jax.tree_util.tree_leaves(p_pal)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

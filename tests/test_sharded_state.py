@@ -39,6 +39,7 @@ force_host_device_count(8)
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke
 from repro.core import OptimizerConfig, build_optimizer
+from repro.launch.mesh import make_mesh
 from repro.launch.shardmap_fsdp import make_shardmap_train_step
 from repro.models import build_model
 
@@ -53,7 +54,7 @@ def run(opt_name, n, shard_state, steps=7):
     opt = build_optimizer(OptimizerConfig(
         name=opt_name, lr=1e-2, rank=4, gamma=1, period=3, projector="svd",
         fuse_families=True))
-    mesh = jax.make_mesh((n,), ("data",), devices=jax.devices()[:n])
+    mesh = make_mesh((n,), ("data",), devices=jax.devices()[:n])
     _, jit_builder = make_shardmap_train_step(
         model, opt, mesh, grad_clip=1.0, shard_state=shard_state)
     p, s = copy(params), opt.init(copy(params))
@@ -98,6 +99,7 @@ import jax
 from repro.configs import RunConfig, get_smoke
 from repro.core import OptimizerConfig
 from repro.data import DataConfig
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.train import Trainer
 
@@ -108,7 +110,7 @@ opt_cfg = OptimizerConfig(name="gum", lr=1e-2, rank=4, gamma=1, period=3,
                           shard_state=True)
 data_cfg = DataConfig(vocab=cfg.vocab, seq_len=64, global_batch=8,
                       num_hosts=1, host_id=0)
-mesh = jax.make_mesh((4,), ("data",))
+mesh = make_mesh((4,), ("data",), devices=jax.devices()[:4])
 CKPT = "/tmp/repro_ckpt_zero_resume"
 shutil.rmtree(CKPT, ignore_errors=True)
 run_cfg = RunConfig(steps=6, ckpt_dir=CKPT, resume=True, ckpt_every=3,
@@ -156,6 +158,7 @@ import jax
 from repro.configs import RunConfig, get_smoke
 from repro.core import OptimizerConfig
 from repro.data import DataConfig
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.train import Trainer
 
@@ -163,7 +166,7 @@ cfg = get_smoke("llama-60m")
 model = build_model(cfg)
 data_cfg = DataConfig(vocab=cfg.vocab, seq_len=64, global_batch=8,
                       num_hosts=1, host_id=0)
-mesh = jax.make_mesh((2,), ("data",))
+mesh = make_mesh((2,), ("data",), devices=jax.devices()[:2])
 
 def run(shard_state, tag):
     ckpt = f"/tmp/repro_ckpt_zero_mig_{tag}"

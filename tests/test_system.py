@@ -54,6 +54,24 @@ def _train(tmpdir, steps, resume=True, seed=0):
     return trainer
 
 
+def test_compile_cache_dir_rule(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins when set; otherwise the cache sits at
+    the fixed <checkout>/.jax_cache (the same path on every run)."""
+    from repro.launch import devices
+
+    assert os.path.isfile(os.path.join(devices.CHECKOUT, "src", "repro",
+                                       "launch", "devices.py"))
+    # the entry points leave the CPU backend (the test suite) uncached
+    assert devices.enable_compile_cache() is None
+    assert (jax.config.jax_compilation_cache_dir
+            == os.environ.get("JAX_COMPILATION_CACHE_DIR"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/cache/elsewhere")
+    assert devices.compile_cache_dir() == "/cache/elsewhere"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert devices.compile_cache_dir() == os.path.join(devices.CHECKOUT,
+                                                       ".jax_cache")
+
+
 @pytest.mark.slow
 def test_trainer_resume_exact(tmp_path):
     """train(12) straight == train(8) + crash + resume to 12 — exact same
