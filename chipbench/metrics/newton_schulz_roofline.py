@@ -1,0 +1,7 @@
+"""Newton–Schulz kernels: the least time their work needs at the called
+shapes over the device time of their events, per steady step."""
+from chipbench import kernels
+
+
+def read(run):
+    return kernels.roofline(run, "newton_schulz")
