@@ -33,10 +33,6 @@ test existed).
                               bytes, steady-step time sharded vs
                               replicated (PR 9; writes
                               BENCH_sharded_step.json)
-  telemetry                 — telemetry bus + in-jit instrumentation
-                              overhead vs the <=2% step-time budget, and
-                              bus write throughput (PR 10; writes
-                              BENCH_telemetry.json)
   kernel_micro              — per-kernel wall-time microbenchmarks (CPU
                               interpret/xla; indicative only, not TPU)
 """
@@ -108,7 +104,6 @@ SUITES = [
     "audit_matrix",
     "resilience",
     "sharded_step",
-    "telemetry",
 ]
 
 # Suites that commit a results/BENCH_*.json trajectory.  A registered suite
@@ -121,7 +116,6 @@ RESULT_JSON = {
     "audit_matrix": "BENCH_audit_matrix.json",
     "resilience": "BENCH_resilience.json",
     "sharded_step": "BENCH_sharded_step.json",
-    "telemetry": "BENCH_telemetry.json",
 }
 
 # Suites that deliberately do NOT commit a result JSON — paper-figure

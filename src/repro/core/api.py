@@ -103,7 +103,9 @@ def multi_transform(
         labels = label_fn(params)
         new_inner, upds = {}, {}
         for k, t in transforms.items():
-            u, s = t.update(mask(grads, labels, k), state.inner[k], mask(params, labels, k))
+            with jax.named_scope(k):  # each label is a stage of the step
+                u, s = t.update(mask(grads, labels, k), state.inner[k],
+                                mask(params, labels, k))
             upds[k], new_inner[k] = u, s
         merged = unmask_merge(upds, labels)
         return merged, MultiState(inner=new_inner)
@@ -221,6 +223,6 @@ class OptimizerConfig:
     # sampled per-step bias residual in the spectrum-probe dicts (implies
     # probe_spectrum).  Write-only from the update's point of view — the
     # parameter trajectory is bit-exact with telemetry off, and the state
-    # gains zero leaves when off.  Budgeted <=2% step time
-    # (benchmarks/telemetry.py).
+    # gains zero leaves when off.  What tracing costs on the chip is kept
+    # in PERF.md; these probes' own step time is not measured there yet.
     telemetry: bool = False

@@ -97,6 +97,7 @@ unchanged, so the knob is inert there (they still get the stacking win).
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 from typing import Callable, NamedTuple, Optional
 
@@ -134,6 +135,21 @@ from .lowrank_common import (
 from .newton_schulz import muon_scale, newton_schulz
 
 _IS_NONE = lambda x: x is None
+
+
+def _scoped(name: str):
+    """Decorator: trace the function under ``jax.named_scope(name)`` (a new
+    scope object per call: one object must not be entered twice at once)."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+
+        return scoped
+
+    return wrap
 
 
 def _dispatch():
@@ -199,26 +215,29 @@ class ProjGrad:
     def materialize(self):
         """The projected gradient PᵀG / G P through the dispatch layer
         (coeff NOT applied — elementwise consumers fold it in themselves)."""
-        return _dispatch().project(
-            self.p, self.g, side=self.fs.side, impl=self.kernel_impl,
-            pad_rank_to=self.pad_rank_to,
-        )
+        with jax.named_scope("lowrank.project"):
+            return _dispatch().project(
+                self.p, self.g, side=self.fs.side, impl=self.kernel_impl,
+                pad_rank_to=self.pad_rank_to,
+            )
 
     def fused_momentum(self, mu, beta: float):
         """``beta * mu + coeff * PᵀG`` via the single fused Pallas kernel —
         the per-step hot loop of every momentum-based low-rank optimizer."""
-        return _dispatch().lowrank_update(
-            self.p, self.g, self.apply_reset(mu), beta, self.coeff,
-            side=self.fs.side, impl=self.kernel_impl,
-            pad_rank_to=self.pad_rank_to,
-        )
+        with jax.named_scope("lowrank.project"):
+            return _dispatch().lowrank_update(
+                self.p, self.g, self.apply_reset(mu), beta, self.coeff,
+                side=self.fs.side, impl=self.kernel_impl,
+                pad_rank_to=self.pad_rank_to,
+            )
 
     def back(self, s):
         """Back-project a projected-space array to full shape."""
-        return _dispatch().back_project(
-            self.p, s, side=self.fs.side, impl=self.kernel_impl,
-            pad_rank_to=self.pad_rank_to,
-        )
+        with jax.named_scope("lowrank.back_project"):
+            return _dispatch().back_project(
+                self.p, s, side=self.fs.side, impl=self.kernel_impl,
+                pad_rank_to=self.pad_rank_to,
+            )
 
 
 class FullUpdate:
@@ -343,11 +362,12 @@ class PendingBack:
         """Materialize THIS leaf only (the ungrouped fallback used by
         ``apply_updates``; grouped chains go through
         :func:`materialize_pending` instead)."""
-        if self._resolved_impl() == "jnp":
-            return self._jnp_epilogue_slice(
-                self._jnp_full(), self._w_stack() if self._use_w() else None
-            )
-        return _member_slice(self._materialize_stack(), self)
+        with jax.named_scope("lowrank.back_project"):
+            if self._resolved_impl() == "jnp":
+                return self._jnp_epilogue_slice(
+                    self._jnp_full(), self._w_stack() if self._use_w() else None
+                )
+            return _member_slice(self._materialize_stack(), self)
 
 
 def _member_slice(stacked, leaf: PendingBack):
@@ -377,24 +397,25 @@ def materialize_pending(updates: PyTree) -> PyTree:
     leaves, treedef = jax.tree_util.tree_flatten(updates, is_leaf=_is_pending)
     if not any(isinstance(x, PendingBack) for x in leaves):
         return updates
-    groups: dict[int, list[int]] = {}
-    for pos, leaf in enumerate(leaves):
-        if isinstance(leaf, PendingBack):
-            groups.setdefault(id(leaf.s), []).append(pos)
-    out = list(leaves)
-    for positions in groups.values():
-        head = leaves[positions[0]]
-        if head._resolved_impl() == "jnp":
-            full = head._jnp_full()
-            w = head._w_stack() if any(
-                leaves[p]._use_w() for p in positions
-            ) else None
+    with jax.named_scope("lowrank.back_project"):
+        groups: dict[int, list[int]] = {}
+        for pos, leaf in enumerate(leaves):
+            if isinstance(leaf, PendingBack):
+                groups.setdefault(id(leaf.s), []).append(pos)
+        out = list(leaves)
+        for positions in groups.values():
+            head = leaves[positions[0]]
+            if head._resolved_impl() == "jnp":
+                full = head._jnp_full()
+                w = head._w_stack() if any(
+                    leaves[p]._use_w() for p in positions
+                ) else None
+                for pos in positions:
+                    out[pos] = leaves[pos]._jnp_epilogue_slice(full, w)
+                continue
+            full = head._materialize_stack()
             for pos in positions:
-                out[pos] = leaves[pos]._jnp_epilogue_slice(full, w)
-            continue
-        full = head._materialize_stack()
-        for pos in positions:
-            out[pos] = _member_slice(full, leaves[pos])
+                out[pos] = _member_slice(full, leaves[pos])
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
@@ -1097,30 +1118,32 @@ def lowrank(
         inner_wants_params = bool(getattr(inner.update, "wants_params", False))
         fam_msgs, fam_projs, fam_params, fam_probes = [], [], [], []
         for fi, fam in enumerate(plan.families):
-            g32 = stack_family(
-                fam, [g if g is None else g.astype(jnp.float32)
-                      for g in g_leaves]
-            )
+            with jax.named_scope("lowrank.project"):
+                g32 = stack_family(
+                    fam, [g if g is None else g.astype(jnp.float32)
+                          for g in g_leaves]
+                )
             keys_proj, keys_samp = _family_keys(fam, base_key)
             if external_refresh:
                 p_proj = state.projs[fi]
             else:
-                p_proj = jax.lax.cond(
-                    refresh,
-                    lambda _, fam=fam, g32=g32, kp=keys_proj:
-                        _refresh_projectors(fam, g32, kp),
-                    lambda _, fi=fi: state.projs[fi],
-                    None,
-                )
-            if probe_spectrum and not external_refresh:
-                fam_probes.append(jax.lax.cond(
-                    refresh,
-                    lambda _, p=p_proj, g=g32, fam=fam, fi=fi:
-                        _probe_fresh(p, state.projs[fi], g, fam.fs,
-                                     state.probes[fi]),
-                    lambda _, fi=fi: state.probes[fi],
-                    None,
-                ))
+                with jax.named_scope("lowrank.refresh"):
+                    p_proj = jax.lax.cond(
+                        refresh,
+                        lambda _, fam=fam, g32=g32, kp=keys_proj:
+                            _refresh_projectors(fam, g32, kp),
+                        lambda _, fi=fi: state.projs[fi],
+                        None,
+                    )
+                    if probe_spectrum:
+                        fam_probes.append(jax.lax.cond(
+                            refresh,
+                            lambda _, p=p_proj, g=g32, fam=fam, fi=fi:
+                                _probe_fresh(p, state.projs[fi], g, fam.fs,
+                                             state.probes[fi]),
+                            lambda _, fi=fi: state.probes[fi],
+                            None,
+                        ))
             fam_msgs.append(ProjGrad(
                 p=p_proj, g=g32, fs=fam.fs, kernel_impl=kernel_impl,
                 pad_rank_to=pad_rank_to, coeff=1.0,
@@ -1129,9 +1152,10 @@ def lowrank(
                 key=keys_samp, seg=fam.seg,
             ))
             fam_projs.append(p_proj)
-            fam_params.append(
-                stack_family(fam, leaves) if inner_wants_params else None
-            )
+            with jax.named_scope("lowrank.project"):
+                fam_params.append(
+                    stack_family(fam, leaves) if inner_wants_params else None
+                )
 
         if telemetry and not external_refresh:
             _sample_bias(
@@ -1141,12 +1165,15 @@ def lowrank(
                 fam_probes,
             )
 
-        inner_out, new_inner = inner.update(fam_msgs, state.inner, fam_params)
+        with jax.named_scope("lowrank.inner"):
+            inner_out, new_inner = inner.update(fam_msgs, state.inner, fam_params)
 
         out_leaves = [None] * plan.n_leaves
         for fam, msg, o, w in zip(plan.families, fam_msgs, inner_out, fam_params):
             if isinstance(o, FullUpdate):
-                for i, part in zip(fam.members, unstack_family(fam, o.u)):
+                with jax.named_scope("lowrank.back_project"):
+                    parts = unstack_family(fam, o.u)
+                for i, part in zip(fam.members, parts):
                     out_leaves[i] = part
             elif fused_epilogue:
                 if w is None:
@@ -1159,7 +1186,10 @@ def lowrank(
                         member_lead=fam.member_fs.lead,
                     )
             else:
-                for i, part in zip(fam.members, unstack_family(fam, msg.back(o))):
+                parts = msg.back(o)
+                with jax.named_scope("lowrank.back_project"):
+                    parts = unstack_family(fam, parts)
+                for i, part in zip(fam.members, parts):
                     out_leaves[i] = part
 
         return (
@@ -1171,6 +1201,7 @@ def lowrank(
             ),
         )
 
+    @_scoped("lowrank.refresh")
     def refresh_fused(grads: PyTree, state: LowRankState, params: PyTree) -> LowRankState:
         count = state.count + 1
         refresh_now = (count - 1) % period == 0
@@ -1261,29 +1292,33 @@ def lowrank(
                 continue
             fs = family_shape(p, rank)
             key_proj, key_samp = _leaf_key(base_key, i)
-            g32 = g.astype(jnp.float32)
+            with jax.named_scope("lowrank.project"):
+                g32 = g.astype(jnp.float32)
             if external_refresh:
                 p_proj = proj
             else:
-                p_proj = jax.lax.cond(
-                    refresh,
-                    lambda _: compute_projectors(
-                        projector, g32, fs.rank, key_proj, fs.side, subspace_iters
-                    ),
-                    lambda _: proj,
-                    None,
-                )
+                with jax.named_scope("lowrank.refresh"):
+                    p_proj = jax.lax.cond(
+                        refresh,
+                        lambda _: compute_projectors(
+                            projector, g32, fs.rank, key_proj, fs.side,
+                            subspace_iters
+                        ),
+                        lambda _: proj,
+                        None,
+                    )
             if probe_spectrum:
                 if external_refresh:
                     probe_leaves.append(pr_leaves[i])
                 else:
-                    probe_leaves.append(jax.lax.cond(
-                        refresh,
-                        lambda _, p=p_proj, old=proj, g=g32, fs=fs, i=i:
-                            _probe_fresh(p, old, g, fs, pr_leaves[i]),
-                        lambda _, i=i: pr_leaves[i],
-                        None,
-                    ))
+                    with jax.named_scope("lowrank.refresh"):
+                        probe_leaves.append(jax.lax.cond(
+                            refresh,
+                            lambda _, p=p_proj, old=proj, g=g32, fs=fs, i=i:
+                                _probe_fresh(p, old, g, fs, pr_leaves[i]),
+                            lambda _, i=i: pr_leaves[i],
+                            None,
+                        ))
                     lr_sites.append((i, p_proj, g32, fs.side))
             msg_leaves.append(ProjGrad(
                 p=p_proj, g=g32, fs=fs, kernel_impl=kernel_impl,
@@ -1298,7 +1333,8 @@ def lowrank(
             _sample_bias(count, lr_sites, probe_leaves)
 
         inner_updates = jax.tree_util.tree_unflatten(treedef, msg_leaves)
-        inner_out, new_inner = inner.update(inner_updates, state.inner, params)
+        with jax.named_scope("lowrank.inner"):
+            inner_out, new_inner = inner.update(inner_updates, state.inner, params)
 
         out_leaves = []
         for msg, o, p in zip(msg_leaves, treedef.flatten_up_to(inner_out), leaves):
@@ -1325,6 +1361,7 @@ def lowrank(
             ),
         )
 
+    @_scoped("lowrank.refresh")
     def refresh(grads: PyTree, state: LowRankState, params: PyTree) -> LowRankState:
         """External period-boundary refresh against raw gradients: recompute
         projectors, resample the inner transform's block assignments, zero
@@ -1524,67 +1561,69 @@ def layerwise_unbias(
 
         low_upds, new_idx, full_upds, full_params = [], [], [], []
         refresh_any = False
-        for g, idx, p in zip(g_leaves, idx_leaves, param_leaves):
-            if g is None:
-                low_upds.append(None)
-                new_idx.append(None)
-                full_upds.append(None)
-                full_params.append(None)
-                continue
-            if not isinstance(g, ProjGrad):
-                raise TypeError(
-                    "layerwise_unbias must be composed inside lowrank() "
-                    f"(got a {type(g).__name__} leaf)"
-                )
-            fs = g.fs
-            g_f, q, c_low, c_comp, c_full = _coeffs(fs, g.seg)
-            # q >= 1: no low branch at all (state is None too — see init)
-            low_upds.append(g.with_coeff(c_low) if q < 1.0 else None)
-            if g_f == 0:
-                new_idx.append(None)
-                full_upds.append(None)
-                full_params.append(None)
-                continue
-            if g.refresh is False:  # static: external-refresh mode
-                idx2 = idx
-            else:
-                refresh_any = g.refresh
-                if g.seg is not None:
-                    fresh = _member_sample(
-                        g.key, g.seg.members, g.seg.member_L, g_f
+        # The gamma full-rank slots: sample, gather, base update, scatter.
+        with jax.named_scope("unbias.full_slots"):
+            for g, idx, p in zip(g_leaves, idx_leaves, param_leaves):
+                if g is None:
+                    low_upds.append(None)
+                    new_idx.append(None)
+                    full_upds.append(None)
+                    full_params.append(None)
+                    continue
+                if not isinstance(g, ProjGrad):
+                    raise TypeError(
+                        "layerwise_unbias must be composed inside lowrank() "
+                        f"(got a {type(g).__name__} leaf)"
                     )
+                fs = g.fs
+                g_f, q, c_low, c_comp, c_full = _coeffs(fs, g.seg)
+                # q >= 1: no low branch at all (state is None too — see init)
+                low_upds.append(g.with_coeff(c_low) if q < 1.0 else None)
+                if g_f == 0:
+                    new_idx.append(None)
+                    full_upds.append(None)
+                    full_params.append(None)
+                    continue
+                if g.refresh is False:  # static: external-refresh mode
+                    idx2 = idx
                 else:
-                    fresh = jax.random.choice(
-                        g.key, fs.L, (g_f,), replace=False
-                    ).astype(jnp.int32)
-                idx2 = jnp.where(g.refresh, fresh, idx)
-            new_idx.append(idx2)
-            g_s = gather_blocks(g.g, idx2, fs)        # (gamma, m, n)
-            p_s = gather_blocks(g.p, idx2, fs)        # (gamma, s, r)
-            pptg = d.back_project(
-                p_s,
-                d.project(p_s, g_s, side=fs.side, impl=g.kernel_impl,
-                          pad_rank_to=g.pad_rank_to),
-                side=fs.side, impl=g.kernel_impl, pad_rank_to=g.pad_rank_to,
-            )
-            resid = g_s - c_comp * pptg
-            full_upds.append(c_full * resid)
-            full_params.append(gather_blocks(p, idx2, fs))
-
-        # Slot -> block assignments change at the boundary, so the slots'
-        # base momenta always reset there (independent of reset_on_refresh).
-        full_state = state.full
-        if refresh_any is not False:
-            full_state = _reset_floats(state.full, refresh_any)
+                    refresh_any = g.refresh
+                    if g.seg is not None:
+                        fresh = _member_sample(
+                            g.key, g.seg.members, g.seg.member_L, g_f
+                        )
+                    else:
+                        fresh = jax.random.choice(
+                            g.key, fs.L, (g_f,), replace=False
+                        ).astype(jnp.int32)
+                    idx2 = jnp.where(g.refresh, fresh, idx)
+                new_idx.append(idx2)
+                g_s = gather_blocks(g.g, idx2, fs)        # (gamma, m, n)
+                p_s = gather_blocks(g.p, idx2, fs)        # (gamma, s, r)
+                pptg = d.back_project(
+                    p_s,
+                    d.project(p_s, g_s, side=fs.side, impl=g.kernel_impl,
+                              pad_rank_to=g.pad_rank_to),
+                    side=fs.side, impl=g.kernel_impl, pad_rank_to=g.pad_rank_to,
+                )
+                resid = g_s - c_comp * pptg
+                full_upds.append(c_full * resid)
+                full_params.append(gather_blocks(p, idx2, fs))
 
         low_out, new_low = base.update(
             jax.tree_util.tree_unflatten(treedef, low_upds), state.low, params
         )
-        full_out, new_full = base.update(
-            jax.tree_util.tree_unflatten(treedef, full_upds),
-            full_state,
-            jax.tree_util.tree_unflatten(treedef, full_params),
-        )
+        with jax.named_scope("unbias.full_slots"):
+            # Slot -> block assignments change at the boundary, so the slots'
+            # base momenta always reset there (independent of reset_on_refresh).
+            full_state = state.full
+            if refresh_any is not False:
+                full_state = _reset_floats(state.full, refresh_any)
+            full_out, new_full = base.update(
+                jax.tree_util.tree_unflatten(treedef, full_upds),
+                full_state,
+                jax.tree_util.tree_unflatten(treedef, full_params),
+            )
 
         lo_leaves = treedef.flatten_up_to(low_out)
         fo_leaves = treedef.flatten_up_to(full_out)
@@ -1600,7 +1639,8 @@ def layerwise_unbias(
             else:
                 u = jnp.zeros(fs.lead + (fs.m, fs.n), jnp.float32)
             if g_f > 0:
-                u = scatter_blocks(u, idx2, fo, fs)
+                with jax.named_scope("unbias.full_slots"):
+                    u = scatter_blocks(u, idx2, fo, fs)
             outs.append(FullUpdate(u))
 
         return (

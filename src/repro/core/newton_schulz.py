@@ -49,28 +49,31 @@ def newton_schulz(
     from repro.kernels import launch_count
 
     launch_count.record("newton_schulz")
-    a, b, c = NS_COEFFS
-    orig_dtype = x.dtype
-    x = x.astype(jnp.float32)
+    # The stage name the kernels' dispatcher gives its jnp path, so the
+    # device trace names this path alike whichever entry point it took.
+    with jax.named_scope("newton_schulz.jnp"):
+        a, b, c = NS_COEFFS
+        orig_dtype = x.dtype
+        x = x.astype(jnp.float32)
 
-    transposed = x.shape[-2] > x.shape[-1]
-    if transposed:
-        x = jnp.swapaxes(x, -1, -2)
+        transposed = x.shape[-2] > x.shape[-1]
+        if transposed:
+            x = jnp.swapaxes(x, -1, -2)
 
-    # Spectral-norm-ish normalization so singular values land in the basin.
-    norm = jnp.linalg.norm(x, axis=(-2, -1), keepdims=True)
-    x = x / (norm + eps)
+        # Spectral-norm-ish normalization so singular values land in the basin.
+        norm = jnp.linalg.norm(x, axis=(-2, -1), keepdims=True)
+        x = x / (norm + eps)
 
-    def body(_, x):
-        xxt = x @ jnp.swapaxes(x, -1, -2)          # (..., m, m), m <= n
-        bxx = b * xxt + c * (xxt @ xxt)            # quintic combination
-        return a * x + bxx @ x
+        def body(_, x):
+            xxt = x @ jnp.swapaxes(x, -1, -2)          # (..., m, m), m <= n
+            bxx = b * xxt + c * (xxt @ xxt)            # quintic combination
+            return a * x + bxx @ x
 
-    x = jax.lax.fori_loop(0, steps, body, x)
+        x = jax.lax.fori_loop(0, steps, body, x)
 
-    if transposed:
-        x = jnp.swapaxes(x, -1, -2)
-    return x.astype(orig_dtype)
+        if transposed:
+            x = jnp.swapaxes(x, -1, -2)
+        return x.astype(orig_dtype)
 
 
 def msign_exact(x: jax.Array) -> jax.Array:

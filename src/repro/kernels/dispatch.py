@@ -32,7 +32,11 @@ deliberately do not have:
   * per-device calls under a mesh — GSPMD cannot partition a Pallas TPU
     kernel (the compiler refuses it), so under a context mesh each call
     runs through ``jax.shard_map`` on every device's share of the family
-    (:func:`_per_device`).
+    (:func:`_per_device`);
+  * a stage name per call — each op traces under ``jax.named_scope(
+    "<op>.<impl>")`` with the implementation it resolved to
+    (``newton_schulz.pallas``, ``project.jnp``, ...), so a device trace
+    tells a kernel from its jnp fallback by the op's name stack.
 
 ``KernelEntry``/``REGISTRY`` (re-exported as ``repro.kernels.KERNEL_REGISTRY``)
 name each dispatched op with its reference oracle and legality predicate, so
@@ -115,6 +119,7 @@ def _resolve_legal(impl: str, op: str, legal: bool, shape) -> str:
         launch_count.record_fallback(op, tuple(int(d) for d in shape))
         return "jnp"
     return impl
+
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -253,16 +258,17 @@ def lowrank_update(
     impl = _resolve_legal(impl, "lowrank_update",
                           lowrank_update_supported(p, g, side), g.shape)
     launch_count.record("lowrank_update")
-    if impl == "jnp":
-        return beta * r_state.astype(jnp.float32) + coeff * _project_jnp(p, g, side)
+    with jax.named_scope(f"lowrank_update.{impl}"):
+        if impl == "jnp":
+            return beta * r_state.astype(jnp.float32) + coeff * _project_jnp(p, g, side)
 
-    pk, gk, rk, (lead, r, n, bm, bn) = _lowrank_kernel_form(
-        p, g, r_state, side, pad_rank_to
-    )
-    out = _per_device(functools.partial(
-        lowrank_update_batched, beta=beta, coeff=coeff, block_m=bm,
-        block_n=bn, interpret=(impl == "interpret")), pk, gk, rk)
-    return _lowrank_unkernel_form(out, lead, r, n, side)
+        pk, gk, rk, (lead, r, n, bm, bn) = _lowrank_kernel_form(
+            p, g, r_state, side, pad_rank_to
+        )
+        out = _per_device(functools.partial(
+            lowrank_update_batched, beta=beta, coeff=coeff, block_m=bm,
+            block_n=bn, interpret=(impl == "interpret")), pk, gk, rk)
+        return _lowrank_unkernel_form(out, lead, r, n, side)
 
 
 def project(p: jax.Array, g: jax.Array, *, side: str = "left",
@@ -273,16 +279,17 @@ def project(p: jax.Array, g: jax.Array, *, side: str = "left",
     impl = _resolve_legal(impl, "project",
                           lowrank_update_supported(p, g, side), g.shape)
     launch_count.record("project")
-    if impl == "jnp":
-        return _project_jnp(p, g, side)
+    with jax.named_scope(f"project.{impl}"):
+        if impl == "jnp":
+            return _project_jnp(p, g, side)
 
-    pk, gk, _, (lead, r, n, bm, bn) = _lowrank_kernel_form(
-        p, g, None, side, pad_rank_to
-    )
-    out = _per_device(functools.partial(
-        project_batched, coeff=1.0, block_m=bm, block_n=bn,
-        interpret=(impl == "interpret")), pk, gk)
-    return _lowrank_unkernel_form(out, lead, r, n, side)
+        pk, gk, _, (lead, r, n, bm, bn) = _lowrank_kernel_form(
+            p, g, None, side, pad_rank_to
+        )
+        out = _per_device(functools.partial(
+            project_batched, coeff=1.0, block_m=bm, block_n=bn,
+            interpret=(impl == "interpret")), pk, gk)
+        return _lowrank_unkernel_form(out, lead, r, n, side)
 
 
 # --------------------------------------------------------------------------
@@ -345,16 +352,17 @@ def back_project(p: jax.Array, s: jax.Array, *, side: str = "left",
     impl = _resolve_legal(impl, "back_project",
                           back_project_supported(p, s, side), s.shape)
     launch_count.record("back_project")
-    if impl == "jnp":
-        return _back_project_jnp(p, s, side)
+    with jax.named_scope(f"back_project.{impl}"):
+        if impl == "jnp":
+            return _back_project_jnp(p, s, side)
 
-    pk, sk, _, (lead, m, n, bm, bn) = _back_project_kernel_form(
-        p, s, None, side, pad_rank_to
-    )
-    out = _per_device(functools.partial(
-        back_project_batched, block_m=bm, block_n=bn,
-        interpret=(impl == "interpret")), pk, sk)
-    return _back_project_unkernel_form(out, lead, m, n, side)
+        pk, sk, _, (lead, m, n, bm, bn) = _back_project_kernel_form(
+            p, s, None, side, pad_rank_to
+        )
+        out = _per_device(functools.partial(
+            back_project_batched, block_m=bm, block_n=bn,
+            interpret=(impl == "interpret")), pk, sk)
+        return _back_project_unkernel_form(out, lead, m, n, side)
 
 
 def back_project_epilogue(
@@ -382,24 +390,25 @@ def back_project_epilogue(
     impl = _resolve_legal(impl, "back_project_epilogue",
                           back_project_supported(p, s, side), s.shape)
     launch_count.record("back_project_epilogue")
-    if impl == "jnp":
-        out = scale * _back_project_jnp(p, s, side)
-        if w is not None:
-            out = out + decay * w.astype(jnp.float32)
-        return out
+    with jax.named_scope(f"back_project_epilogue.{impl}"):
+        if impl == "jnp":
+            out = scale * _back_project_jnp(p, s, side)
+            if w is not None:
+                out = out + decay * w.astype(jnp.float32)
+            return out
 
-    pk, sk, wk, (lead, m, n, bm, bn) = _back_project_kernel_form(
-        p, s, w, side, pad_rank_to
-    )
-    sd = jnp.stack([jnp.asarray(scale, jnp.float32),
-                    jnp.asarray(decay, jnp.float32)]).reshape(1, 2)
-    kernel = functools.partial(back_project_epilogue_batched, block_m=bm,
-                               block_n=bn, interpret=(impl == "interpret"))
-    if wk is None:
-        out = _per_device(lambda p, s, sd: kernel(p, s, None, sd), pk, sk, sd)
-    else:
-        out = _per_device(kernel, pk, sk, wk, sd)
-    return _back_project_unkernel_form(out, lead, m, n, side)
+        pk, sk, wk, (lead, m, n, bm, bn) = _back_project_kernel_form(
+            p, s, w, side, pad_rank_to
+        )
+        sd = jnp.stack([jnp.asarray(scale, jnp.float32),
+                        jnp.asarray(decay, jnp.float32)]).reshape(1, 2)
+        kernel = functools.partial(back_project_epilogue_batched, block_m=bm,
+                                   block_n=bn, interpret=(impl == "interpret"))
+        if wk is None:
+            out = _per_device(lambda p, s, sd: kernel(p, s, None, sd), pk, sk, sd)
+        else:
+            out = _per_device(kernel, pk, sk, wk, sd)
+        return _back_project_unkernel_form(out, lead, m, n, side)
 
 
 # --------------------------------------------------------------------------
@@ -436,32 +445,32 @@ def newton_schulz(
     impl = _resolve_legal(impl, "newton_schulz", newton_schulz_supported(x),
                           x.shape)
     if impl == "jnp":
-        # ns_jnp records the launch itself (jnp body), so don't double count.
+        # ns_jnp records the launch and opens newton_schulz.jnp itself.
         return ns_jnp(x, steps=steps, eps=eps)
     launch_count.record("newton_schulz")
+    with jax.named_scope(f"newton_schulz.{impl}"):
+        interpret = impl == "interpret"
+        orig_dtype = x.dtype
+        lead = x.shape[:-2]
 
-    interpret = impl == "interpret"
-    orig_dtype = x.dtype
-    lead = x.shape[:-2]
+        transposed = x.shape[-2] > x.shape[-1]
+        if transposed:
+            x = jnp.swapaxes(x, -1, -2)
+        m, n = int(x.shape[-2]), int(x.shape[-1])
+        # Zero padding is exact for NS: padded rows/cols of X are zero, stay zero
+        # through every iteration (Gram gains zero blocks; a·X + A2·X preserves
+        # them), and the Frobenius norm used for the initial scaling is unchanged.
+        m_pad = _round_up(m, _SUBLANE)
+        n_pad, bn = _pad_and_block(n, _ns_block_target(m_pad, block_n), _LANE)
+        xk = _flatten_lead(_pad_axis(_pad_axis(x, -2, m_pad), -1, n_pad))
 
-    transposed = x.shape[-2] > x.shape[-1]
-    if transposed:
-        x = jnp.swapaxes(x, -1, -2)
-    m, n = int(x.shape[-2]), int(x.shape[-1])
-    # Zero padding is exact for NS: padded rows/cols of X are zero, stay zero
-    # through every iteration (Gram gains zero blocks; a·X + A2·X preserves
-    # them), and the Frobenius norm used for the initial scaling is unchanged.
-    m_pad = _round_up(m, _SUBLANE)
-    n_pad, bn = _pad_and_block(n, _ns_block_target(m_pad, block_n), _LANE)
-    xk = _flatten_lead(_pad_axis(_pad_axis(x, -2, m_pad), -1, n_pad))
-
-    out = _per_device(functools.partial(
-        newton_schulz_pallas, steps=steps, eps=eps, block_n=bn,
-        interpret=interpret), xk)[..., :m, :n]
-    out = out.reshape(lead + (m, n))
-    if transposed:
-        out = jnp.swapaxes(out, -1, -2)
-    return out.astype(orig_dtype)
+        out = _per_device(functools.partial(
+            newton_schulz_pallas, steps=steps, eps=eps, block_n=bn,
+            interpret=interpret), xk)[..., :m, :n]
+        out = out.reshape(lead + (m, n))
+        if transposed:
+            out = jnp.swapaxes(out, -1, -2)
+        return out.astype(orig_dtype)
 
 
 # --------------------------------------------------------------------------

@@ -168,6 +168,14 @@ def make_train_step(
     low-rank stage — the dead-subspace detector's signal).  Both update
     norms share one fused subtract-square-reduce pass over the delta, so
     the whole monitor costs a single extra pass per step.
+
+    **Stage names.** Every op of the step sits under exactly one
+    ``jax.named_scope`` (visible in the compiled program's op metadata and
+    so in a device trace): ``loss_and_grads`` (the forward/backward and any
+    accumulation), ``grad_clip``, ``optimizer`` (``optimizer.update``, and
+    ``apply_updates`` inside it) and ``guard`` (the fault gate, the
+    non-finite guard's reverts and the metrics).  No name contains ``jvp``
+    or ``transpose``, which autodiff puts in the name stack itself.
     """
     cfg = model.cfg
 
@@ -184,6 +192,31 @@ def make_train_step(
         )
 
     def _step(params, opt_state, batch, fault):
+        with jax.named_scope("loss_and_grads"):
+            loss, grads = _loss_and_grads(params, batch)
+        with jax.named_scope("guard"):
+            if fault_gate is not None:
+                grads = fault_gate.apply(grads, fault)
+        with jax.named_scope("grad_clip"):
+            grads, gnorm_raw = _clip(grads)
+        with jax.named_scope("guard"):
+            gnorm, finite, grads = _guard_grads(loss, grads)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = optimizer.update(grads, opt_state, params)
+        with jax.named_scope("guard"):
+            updates, opt_state = _guard_update(finite, updates, new_opt_state,
+                                               opt_state)
+        with jax.named_scope("optimizer"), jax.named_scope("apply_updates"):
+            new_params = apply_updates(params, updates)
+        with jax.named_scope("guard"):
+            metrics = {"loss": loss.astype(jnp.float32),
+                       "grad_norm": gnorm,
+                       "update_applied": finite}
+            if extra_metrics:
+                metrics.update(_health_metrics(gnorm_raw, new_params, params))
+        return new_params, opt_state, metrics
+
+    def _loss_and_grads(params, batch):
         if microbatches > 1:
             def slice_mb(x):
                 B = x.shape[0]
@@ -211,9 +244,9 @@ def make_train_step(
             grads = jax.tree_util.tree_map(lambda g: g / microbatches, grads)
         else:
             loss, grads = single_grad(params, batch)
+        return loss, grads
 
-        if fault_gate is not None:
-            grads = fault_gate.apply(grads, fault)
+    def _clip(grads):
         if extra_metrics:
             # The clip computes this exact reduction internally; doing it
             # here and clipping inline keeps grad_norm_raw free of an extra
@@ -227,50 +260,29 @@ def make_train_step(
             gnorm_raw = None
             if grad_clip > 0:
                 grads = clip_by_global_norm(grads, grad_clip)
+        return grads, gnorm_raw
 
-        # NaN/Inf guard — resilience rung 0; see the docstring above.
-        gnorm = core_api.global_norm(grads)
-        finite = jnp.isfinite(loss) & jnp.isfinite(gnorm)
-        grads = jax.tree_util.tree_map(
-            lambda g: jnp.where(finite, g, jnp.zeros_like(g)), grads
-        )
-        updates, new_opt_state = optimizer.update(grads, opt_state, params)
-        updates = jax.tree_util.tree_map(
-            lambda u: None if u is None else jnp.where(finite, u, jnp.zeros_like(u)),
-            updates,
-            is_leaf=lambda x: x is None,
-        )
-        opt_state = jax.tree_util.tree_map(
-            lambda new, old: jnp.where(finite, new, old) if hasattr(new, "shape") else new,
-            new_opt_state, opt_state,
-        )
-        new_params = apply_updates(params, updates)
-        metrics = {"loss": loss.astype(jnp.float32),
-                   "grad_norm": gnorm,
-                   "update_applied": finite}
-        if extra_metrics:
-            from repro.core import default_lowrank_filter
+    def _health_metrics(gnorm_raw, new_params, params):
+        from repro.core import default_lowrank_filter
 
-            metrics["grad_norm_raw"] = gnorm_raw
-            # One fused subtract-square-reduce pass per leaf; both norms
-            # combine the same per-leaf partial sums.
-            delta_sq = jax.tree_util.tree_map(
-                lambda a, b: jnp.sum(jnp.square((a - b).astype(jnp.float32))),
-                new_params, params)
-            metrics["update_norm"] = jnp.sqrt(
-                sum(jax.tree_util.tree_leaves(delta_sq)))
-            # Restricted to the leaves the low-rank stage treats
-            # (default_lowrank_filter): a dead subspace zeroes exactly these
-            # while embeddings/norms keep updating, so the global norm would
-            # mask the collapse.
-            lr_paths = core_api.tree_paths(new_params)
-            lr_sq = jax.tree_util.tree_map(
-                lambda p, s, a: s if default_lowrank_filter(p, a)
-                else jnp.zeros((), s.dtype),
-                lr_paths, delta_sq, new_params)
-            metrics["update_norm_lowrank"] = jnp.sqrt(
-                sum(jax.tree_util.tree_leaves(lr_sq)))
-        return new_params, opt_state, metrics
+        # One fused subtract-square-reduce pass per leaf; both norms
+        # combine the same per-leaf partial sums.
+        delta_sq = jax.tree_util.tree_map(
+            lambda a, b: jnp.sum(jnp.square((a - b).astype(jnp.float32))),
+            new_params, params)
+        # Restricted to the leaves the low-rank stage treats
+        # (default_lowrank_filter): a dead subspace zeroes exactly these
+        # while embeddings/norms keep updating, so the global norm would
+        # mask the collapse.
+        lr_paths = core_api.tree_paths(new_params)
+        lr_sq = jax.tree_util.tree_map(
+            lambda p, s, a: s if default_lowrank_filter(p, a)
+            else jnp.zeros((), s.dtype),
+            lr_paths, delta_sq, new_params)
+        return {"grad_norm_raw": gnorm_raw,
+                "update_norm": jnp.sqrt(sum(jax.tree_util.tree_leaves(delta_sq))),
+                "update_norm_lowrank": jnp.sqrt(
+                    sum(jax.tree_util.tree_leaves(lr_sq)))}
 
     if fault_gate is not None:
         def train_step(params, opt_state, batch, fault):
@@ -282,8 +294,34 @@ def make_train_step(
     return train_step
 
 
+def _guard_grads(loss, grads):
+    """NaN/Inf guard, resilience rung 0 (see :func:`make_train_step`):
+    (norm, finite, grads zeroed unless the loss and norm are finite)."""
+    gnorm = core_api.global_norm(grads)
+    finite = jnp.isfinite(loss) & jnp.isfinite(gnorm)
+    grads = jax.tree_util.tree_map(
+        lambda g: jnp.where(finite, g, jnp.zeros_like(g)), grads
+    )
+    return gnorm, finite, grads
+
+
+def _guard_update(finite, updates, new_opt_state, opt_state):
+    """The guard's second half: zero the updates and revert every optimizer
+    state array to its pre-step value unless the step was finite."""
+    updates = jax.tree_util.tree_map(
+        lambda u: None if u is None else jnp.where(finite, u, jnp.zeros_like(u)),
+        updates,
+        is_leaf=lambda x: x is None,
+    )
+    opt_state = jax.tree_util.tree_map(
+        lambda new, old: jnp.where(finite, new, old) if hasattr(new, "shape") else new,
+        new_opt_state, opt_state,
+    )
+    return updates, opt_state
+
+
 def _make_lowrank_accum_step(model, tools, single_grad, grad_clip, microbatches):
-    def train_step(params, opt_state, batch):
+    def accumulate(params, opt_state, batch):
         def slice_mb(x):
             B = x.shape[0]
             return x.reshape((microbatches, B // microbatches) + x.shape[1:])
@@ -312,27 +350,26 @@ def _make_lowrank_accum_step(model, tools, single_grad, grad_clip, microbatches)
         acc = jax.tree_util.tree_map(
             lambda a: a / microbatches, acc
         )
-        grads = tools.reconstruct(acc, opt_state, params)
-        if grad_clip > 0:
-            grads = clip_by_global_norm(grads, grad_clip)
-        gnorm = core_api.global_norm(grads)
-        finite = jnp.isfinite(loss) & jnp.isfinite(gnorm)
-        grads = jax.tree_util.tree_map(
-            lambda g: jnp.where(finite, g, jnp.zeros_like(g)), grads
-        )
-        updates, new_opt_state = tools.transform.update(grads, opt_state, params)
-        updates = jax.tree_util.tree_map(
-            lambda u: None if u is None else jnp.where(finite, u, jnp.zeros_like(u)),
-            updates,
-            is_leaf=lambda x: x is None,
-        )
-        opt_state = jax.tree_util.tree_map(
-            lambda new, old: jnp.where(finite, new, old) if hasattr(new, "shape") else new,
-            new_opt_state, opt_state,
-        )
-        params = apply_updates(params, updates)
-        metrics = {"loss": loss.astype(jnp.float32), "grad_norm": gnorm,
-                   "update_applied": finite}
+        return loss, tools.reconstruct(acc, opt_state, params), opt_state
+
+    def train_step(params, opt_state, batch):
+        with jax.named_scope("loss_and_grads"):
+            loss, grads, opt_state = accumulate(params, opt_state, batch)
+        with jax.named_scope("grad_clip"):
+            if grad_clip > 0:
+                grads = clip_by_global_norm(grads, grad_clip)
+        with jax.named_scope("guard"):
+            gnorm, finite, grads = _guard_grads(loss, grads)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = tools.transform.update(grads, opt_state, params)
+        with jax.named_scope("guard"):
+            updates, opt_state = _guard_update(finite, updates, new_opt_state,
+                                               opt_state)
+        with jax.named_scope("optimizer"), jax.named_scope("apply_updates"):
+            params = apply_updates(params, updates)
+        with jax.named_scope("guard"):
+            metrics = {"loss": loss.astype(jnp.float32), "grad_norm": gnorm,
+                       "update_applied": finite}
         return params, opt_state, metrics
 
     return train_step

@@ -25,8 +25,15 @@ Three layers:
 
 The in-jit half lives in ``repro.core.combinators.lowrank(telemetry=True)``
 (riding the spectrum-probe mechanism — zero extra state leaves when off,
-loss-trajectory bit-exact when on) and is budgeted at <= 2% step time in
-``benchmarks/telemetry.py`` / ``results/BENCH_telemetry.json``.
+loss-trajectory bit-exact when on).  What tracing costs on the chip is
+kept in ``PERF.md`` beside the benchmark (``chipbench/``); the in-jit
+probes' own step time is not measured there yet.
+
+The bus's spans are also ``jax.profiler`` annotations, so a profile of a
+run (``--profile-steps A:B``) puts the trainer's host phases (``step``,
+``feed``, ``dispatch``, ``block``, ``loss``, ``ckpt_save``) on the clock of
+the device ops; the compiled step names its own stages with
+``jax.named_scope`` (``launch.steps.make_train_step``).
 """
 from .bus import (
     SCHEMA_VERSION,

@@ -5,7 +5,10 @@ Record types (one JSON object per ``events.jsonl`` line, ``kind`` tagged):
   header    — first line of every log: ``schema`` version + run metadata
   metric    — scalar sample:  {step, name, value, tags?}
   event     — discrete occurrence: {step?, name, severity, detail, data?}
-  span      — host-side timing: {step?, name, dur_us, tags?}
+  span      — host-side timing: {step?, name, dur_us, tags?}; a span
+              opened with :meth:`Telemetry.span` is also a
+              ``jax.profiler.TraceAnnotation`` of its name, so it shows on
+              the profiler's clock beside the device ops
   counters  — closing summary: cumulative event counts + per-span
               aggregates (count / total_us / mean_us)
 
@@ -25,8 +28,9 @@ Sinks:
                          guaranteeing the JSONL saw the same record
   :class:`MemorySink`  — bounded in-memory ring (tests, report unit checks)
 
-The bus itself is synchronous and dependency-free; emitting with no sinks
-attached is a no-op, so call sites never need a null-object guard.
+The bus itself is synchronous; emitting with no sinks attached is a no-op,
+so call sites never need a null-object guard.  Its one dependency is the
+``jax.profiler`` annotation a span opens.
 """
 from __future__ import annotations
 
@@ -206,11 +210,23 @@ class Telemetry:
 
     @contextlib.contextmanager
     def span(self, name: str, *, step: Optional[int] = None, **tags):
+        """Time the body into a ``span`` record, inside a profiler
+        annotation of the same name (:meth:`annotate`)."""
         t0 = self.clock()
         try:
-            yield
+            with self.annotate(name):
+                yield
         finally:
             self.record_span(name, self.clock() - t0, step=step, **tags)
+
+    @staticmethod
+    def annotate(name: str):
+        """A host span on the ``jax.profiler`` clock and no record: what the
+        host was doing while a trace (``--profile-steps``) runs.  With no
+        trace running it costs one TraceMe check."""
+        from jax.profiler import TraceAnnotation
+
+        return TraceAnnotation(name)
 
     def count(self, name: str, n: int = 1) -> None:
         self.counters[name] = self.counters.get(name, 0) + n

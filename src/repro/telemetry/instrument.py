@@ -158,20 +158,28 @@ def launch_crosscheck(transform, params, *, name: str = "optimizer") -> dict:
     against the closed-form model from :mod:`repro.analysis.launch_model`
     (PR 6) — the static auditor's contract asserted again on the *actual*
     chain about to train, as a telemetry event instead of a hard failure.
-    Returns ``{expected, traced, ok, unmodeled}``; ``ok`` is False when the
-    counts diverge or the model could not account for a stage (RA303)."""
+    Returns ``{expected, traced, ok, unmodeled, fallbacks}``; ``ok`` is False
+    when the counts diverge or the model could not account for a stage
+    (RA303).  ``fallbacks`` lists each ``{op, shape, calls}`` the same trace
+    sent to the jnp reference because the kernel refuses the shape."""
     from repro.analysis.launch_model import expected_launches
     from repro.kernels import launch_count
 
     expected, findings = expected_launches(transform, params, name=name)
     state = jax.eval_shape(transform.init, params)
-    with launch_count.count_launches() as counts:
+    with launch_count.count_launches() as counts, \
+            launch_count.count_fallbacks() as fallbacks:
         jax.make_jaxpr(
             lambda g, s, w: transform.update(g, s, w))(params, state, params)
     traced = dict(counts)
+    calls: dict = {}
+    for op_shape in fallbacks:
+        calls[op_shape] = calls.get(op_shape, 0) + 1
     return {
         "expected": expected,
         "traced": traced,
         "ok": not findings and traced == expected,
         "unmodeled": [f.code for f in findings],
+        "fallbacks": [{"op": op, "shape": list(shape), "calls": n}
+                      for (op, shape), n in sorted(calls.items())],
     }

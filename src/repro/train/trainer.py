@@ -504,6 +504,18 @@ class Trainer:
                     severity="info" if xc["ok"] else "warn",
                     expected=xc["expected"], traced=xc["traced"],
                     unmodeled=xc["unmodeled"])
+                if xc["fallbacks"]:
+                    # what kernel_fallback_ms times on the device: the ops
+                    # whose shapes the kernels refuse, run as jnp instead
+                    self.tele.event(
+                        "dispatch_fallback",
+                        f"audit[{self.opt_cfg.name}]: "
+                        f"{sum(f['calls'] for f in xc['fallbacks'])} "
+                        f"dispatched call(s)/step run jnp for a shape the "
+                        f"kernels refuse: " + ", ".join(
+                            f"{f['op']}{tuple(f['shape'])}"
+                            for f in xc["fallbacks"]),
+                        fallbacks=xc["fallbacks"])
             if self.mesh is not None:
                 # Mesh run: also verify the jitted step's donation wiring on
                 # the lowered module (donated params/opt_state must alias
@@ -555,54 +567,64 @@ class Trainer:
             while step < steps:
                 if self._profile_window is not None:
                     self._profile(step)
+                refresh_step = (self.opt_cfg.period > 0
+                                and step % self.opt_cfg.period == 0)
                 t0 = time.time()
-                if self.rank_ctrl is not None:
-                    opt_state, changed = self.rank_ctrl.maybe_update(
-                        opt_state, params
-                    )
-                    if changed:
-                        self._set_optimizer(self.rank_ctrl.transform())
-                        step_jit = self._jit_step(params, opt_state)
-                        tele.record_span("rank_migration", time.time() - t0,
-                                         step=step)
-                        tele.event("rank_policy",
-                                   f"rank-policy -> "
-                                   f"{self.rank_ctrl.current_map}", step=step,
-                                   map=str(self.rank_ctrl.current_map))
-                if plan is not None:
-                    for ev in plan.state_events(step):
-                        opt_state = poison_projectors(opt_state, ev.kind)
-                        tele.event("fault", f"fault-injection: {ev.kind}",
-                                   step=step, severity="warn", kind=ev.kind)
-                tokens = jnp.asarray(next(stream))
-                if self._fault_gate is not None:
-                    ev = plan.grad_event(step)
-                    if ev is not None:
-                        tele.event("fault", f"fault-injection: {ev.kind}",
-                                   step=step, severity="warn", kind=ev.kind)
-                    fault = (FaultGate.armed(ev) if ev is not None
-                             else FaultGate.disarmed())
-                    args = (params, opt_state, {"tokens": tokens}, fault)
-                else:
-                    args = (params, opt_state, {"tokens": tokens})
-                self._last_step_args = args
-                new_params, new_opt, metrics = step_jit(*args)
-                jax.block_until_ready((new_params, new_opt))
-                loss = float(metrics["loss"])
-                params, opt_state = new_params, new_opt
-                applied = bool(metrics["update_applied"])
+                # The host phases are profiler annotations only (no record):
+                # the names a device trace's idle gaps are attributed to.
+                with tele.span("step", step=step + 1,
+                               kind="refresh" if refresh_step else "steady"):
+                    if self.rank_ctrl is not None:
+                        with tele.annotate("rank_migration"):
+                            opt_state, changed = self.rank_ctrl.maybe_update(
+                                opt_state, params
+                            )
+                            if changed:
+                                self._set_optimizer(self.rank_ctrl.transform())
+                                step_jit = self._jit_step(params, opt_state)
+                        if changed:
+                            tele.record_span("rank_migration",
+                                             time.time() - t0, step=step)
+                            tele.event("rank_policy",
+                                       f"rank-policy -> "
+                                       f"{self.rank_ctrl.current_map}",
+                                       step=step,
+                                       map=str(self.rank_ctrl.current_map))
+                    if plan is not None:
+                        for ev in plan.state_events(step):
+                            opt_state = poison_projectors(opt_state, ev.kind)
+                            tele.event("fault", f"fault-injection: {ev.kind}",
+                                       step=step, severity="warn",
+                                       kind=ev.kind)
+                    with tele.annotate("feed"):
+                        tokens = jnp.asarray(next(stream))
+                    if self._fault_gate is not None:
+                        ev = plan.grad_event(step)
+                        if ev is not None:
+                            tele.event("fault", f"fault-injection: {ev.kind}",
+                                       step=step, severity="warn",
+                                       kind=ev.kind)
+                        fault = (FaultGate.armed(ev) if ev is not None
+                                 else FaultGate.disarmed())
+                        args = (params, opt_state, {"tokens": tokens}, fault)
+                    else:
+                        args = (params, opt_state, {"tokens": tokens})
+                    self._last_step_args = args
+                    with tele.annotate("dispatch"):
+                        new_params, new_opt, metrics = step_jit(*args)
+                    with tele.annotate("block"):
+                        jax.block_until_ready((new_params, new_opt))
+                    with tele.annotate("loss"):
+                        loss = float(metrics["loss"])
+                    params, opt_state = new_params, new_opt
+                    applied = bool(metrics["update_applied"])
+                    dt = time.time() - t0
                 if applied:
                     loss_by_step[step] = loss
                 else:
                     # the step itself zeroed the update (in-jit NaN guard)
                     skipped += 1
-                dt = time.time() - t0
                 step_times.append(dt)
-                refresh_step = (self.opt_cfg.period > 0
-                                and step % self.opt_cfg.period == 0)
-                tele.record_span(
-                    "step", dt, step=step + 1,
-                    kind="refresh" if refresh_step else "steady")
                 if tcfg is not None and (step + 1) % tcfg.every == 0:
                     tele.metric(step + 1, "loss", loss)
                     tele.metric(step + 1, "grad_norm",

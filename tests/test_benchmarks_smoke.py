@@ -28,7 +28,7 @@ def test_run_py_smoke_executes_all_suites(tmp_path):
     for suite in ("synthetic_counterexample", "memory_table", "pretrain_proxy",
                   "bias_residual", "stable_rank", "roofline_report",
                   "optimizer_api", "fused_step", "rank_policy",
-                  "audit_matrix", "resilience", "sharded_step", "telemetry"):
+                  "audit_matrix", "resilience", "sharded_step"):
         assert f"# --- {suite} ---" in res.stderr, suite
     # the fused-step suite produced its rows, including launch counts
     assert "fusedstep_gum_stacked" in out
@@ -45,26 +45,9 @@ def test_run_py_smoke_executes_all_suites(tmp_path):
     # the ZeRO sharded-step suite reported its per-device state row
     assert "sharded_step_state_mesh8," in out
     assert "opt_bytes_per_shard=" in out
-    # the telemetry suite measured the full-path overhead and bus throughput
-    assert "telemetry_step_on" in out
-    assert "telemetry_bus_jsonl" in out
     # registered suites all have their result JSONs committed, and every
     # suite is declared in exactly one of RESULT_JSON / NO_RESULT_JSON
     assert "WARNING: suite" not in res.stderr
     # no result JSONs written in smoke mode (cwd is a scratch dir anyway)
     assert "# wrote" not in out
 
-
-def test_committed_telemetry_result_is_within_budget():
-    """The committed BENCH_telemetry.json must show the telemetry path
-    holding its acceptance budget: full-path step-time overhead <= 2%."""
-    import json
-
-    with open(os.path.join(REPO, "results", "BENCH_telemetry.json")) as f:
-        rec = json.load(f)
-    ovh = rec["overhead"]
-    assert ovh["budget_pct"] == 2.0
-    assert ovh["overhead_pct"] <= ovh["budget_pct"], ovh
-    # throughput sanity: the JSONL sink must sustain well over the handful
-    # of records per step a real run emits
-    assert rec["throughput"]["jsonl_records_per_s"] > 1000
