@@ -115,6 +115,7 @@ from .api import (
 )
 from .api import clip_by_global_norm as _clip_tree
 from .family_plan import (
+    MemberStack,
     build_family_plan,
     member_keys,
     stack_family,
@@ -182,15 +183,21 @@ class ProjInit:
 
 
 class ProjGrad:
-    """Lazy projected gradient leaf handed to transforms inside ``lowrank``."""
+    """Lazy projected gradient leaf handed to transforms inside ``lowrank``.
 
-    __slots__ = ("p", "g", "fs", "kernel_impl", "pad_rank_to", "coeff",
-                 "reset", "refresh", "key", "seg")
+    Under a member layout (``lay``, see :func:`family_layout`) the family's
+    gradient is a :class:`MemberStack` in the members' own FSDP layout:
+    projection and back-projection then run per member layout
+    (:func:`_layout_project` / :func:`_layout_back`), and ``g`` stacks it
+    only for a transform that reads the full stacked gradient itself."""
+
+    __slots__ = ("p", "_g", "fs", "kernel_impl", "pad_rank_to", "coeff",
+                 "reset", "refresh", "key", "seg", "lay")
 
     def __init__(self, p, g, fs, kernel_impl, pad_rank_to=0, coeff=1.0,
-                 reset=None, refresh=False, key=None, seg=None):
+                 reset=None, refresh=False, key=None, seg=None, lay=None):
         self.p = p                      # (*lead, s, r) refreshed projector
-        self.g = g                      # (*lead, m, n) raw fp32 gradient
+        self._g = g                     # (*lead, m, n) raw fp32 gradient
         self.fs = fs                    # FamilyShape (static)
         self.kernel_impl = kernel_impl
         self.pad_rank_to = pad_rank_to
@@ -199,11 +206,17 @@ class ProjGrad:
         self.refresh = refresh          # traced bool period boundary (False = external)
         self.key = key                  # sampling key; (members, 2) when stacked
         self.seg = seg                  # StackSeg under family stacking (or None)
+        self.lay = lay                  # FamilyLayout (g a MemberStack) or None
+
+    @property
+    def g(self):
+        """The raw fp32 gradient, stacked ``(*lead, m, n)``."""
+        return _stacked(self._g)
 
     def with_coeff(self, coeff: float) -> "ProjGrad":
-        return ProjGrad(self.p, self.g, self.fs, self.kernel_impl,
+        return ProjGrad(self.p, self._g, self.fs, self.kernel_impl,
                         self.pad_rank_to, coeff, self.reset, self.refresh,
-                        self.key, self.seg)
+                        self.key, self.seg, self.lay)
 
     def apply_reset(self, x):
         """Zero a momentum buffer at the period boundary (no-op if the
@@ -212,32 +225,80 @@ class ProjGrad:
             return x
         return jnp.where(self.reset, jnp.zeros_like(x), x)
 
+    def gather(self, idx):
+        """The gradient's blocks ``idx`` (the full-rank slots), ``(γ, m, n)``."""
+        return _gather_blocks(self._g, idx, self.fs)
+
+    def full_zeros(self):
+        """Zeros shaped (and laid out) like the gradient."""
+        if isinstance(self._g, MemberStack):
+            return self._g.map(jnp.zeros_like)
+        return jnp.zeros(self.fs.lead + (self.fs.m, self.fs.n), jnp.float32)
+
+    def _project(self):
+        return _layout_project(self._g.fam, self.lay, self.p, self._g.parts,
+                               self.kernel_impl, self.pad_rank_to)
+
     def materialize(self):
         """The projected gradient PᵀG / G P through the dispatch layer
         (coeff NOT applied — elementwise consumers fold it in themselves)."""
         with jax.named_scope("lowrank.project"):
+            if self.lay is not None:
+                return self._project()
             return _dispatch().project(
-                self.p, self.g, side=self.fs.side, impl=self.kernel_impl,
+                self.p, self._g, side=self.fs.side, impl=self.kernel_impl,
                 pad_rank_to=self.pad_rank_to,
             )
 
     def fused_momentum(self, mu, beta: float):
         """``beta * mu + coeff * PᵀG`` via the single fused Pallas kernel —
-        the per-step hot loop of every momentum-based low-rank optimizer."""
+        the per-step hot loop of every momentum-based low-rank optimizer.
+        (Under a member layout the projection's partial sums are reduced
+        across chips first, so the momentum is added after it.)"""
         with jax.named_scope("lowrank.project"):
+            if self.lay is not None:
+                return beta * self.apply_reset(mu) + self.coeff * self._project()
             return _dispatch().lowrank_update(
-                self.p, self.g, self.apply_reset(mu), beta, self.coeff,
+                self.p, self._g, self.apply_reset(mu), beta, self.coeff,
                 side=self.fs.side, impl=self.kernel_impl,
                 pad_rank_to=self.pad_rank_to,
             )
 
-    def back(self, s):
-        """Back-project a projected-space array to full shape."""
+    def back_members(self, s):
+        """Back-project a projected-space array to full shape: a
+        :class:`MemberStack` in the members' layout under a member layout,
+        the stacked array otherwise."""
         with jax.named_scope("lowrank.back_project"):
+            if self.lay is not None:
+                return _layout_back(self._g.fam, self.lay, self.p, s,
+                                    self.kernel_impl, self.pad_rank_to)
             return _dispatch().back_project(
                 self.p, s, side=self.fs.side, impl=self.kernel_impl,
                 pad_rank_to=self.pad_rank_to,
             )
+
+    def back(self, s):
+        """Back-project a projected-space array to full (stacked) shape."""
+        return _stacked(self.back_members(s))
+
+
+def _stacked(x):
+    return x.stacked() if isinstance(x, MemberStack) else x
+
+
+def _gather_blocks(x, idx, fs: FamilyShape):
+    return x.gather(idx) if isinstance(x, MemberStack) else gather_blocks(x, idx, fs)
+
+
+def _scatter_blocks(x, idx, vals, fs: FamilyShape):
+    if isinstance(x, MemberStack):
+        return x.scatter(idx, vals)
+    return scatter_blocks(x, idx, vals, fs)
+
+
+def _members(fam, x) -> list:
+    """Per-member arrays of a family-stacked result."""
+    return x.parts if isinstance(x, MemberStack) else unstack_family(fam, x)
 
 
 class FullUpdate:
@@ -789,40 +850,257 @@ def with_matrix_routing(
 _FAMILY_SHARDING = threading.local()
 
 
+class FamilySharding(NamedTuple):
+    """An active :func:`family_sharding` declaration."""
+
+    mesh: object
+    axis: str
+    member_spec: Optional[Callable] = None  # (path, leaf) -> PartitionSpec
+
+
 @contextlib.contextmanager
-def family_sharding(mesh, axis: str):
+def family_sharding(mesh, axis: str, member_spec: Optional[Callable] = None):
     """Declare that family-stacked low-rank state (projectors + projected
-    moments) is partitioned on mesh ``axis`` along the stack dimension.
+    moments) is partitioned on mesh ``axis``.
 
     Entered by the step builders (``launch.shardmap_fsdp`` /
     ``train.Trainer``) around ``optimizer.update`` at *trace* time; the fused
-    path reads it via :func:`active_family_sharding` and routes each
-    shardable family's projector refresh through a shard-local
-    ``all_gather → SVD → slice`` (the ColossalAI ``distributed_galore``
-    schedule) so the new projectors are born sharded.  Steady-state family
-    math is leading-axis elementwise/batched and needs no collectives — GSPMD
-    partitions it from the in/out shardings alone.  ``mesh`` may be a
-    concrete :class:`jax.sharding.Mesh` or an ``AbstractMesh`` (the
-    collective auditor traces device-free)."""
+    path reads it via :func:`active_family_sharding`.
+
+    Without ``member_spec`` the params are replicated (the ``shard_map``
+    step): every stack partitions along its stack dim, each shardable
+    family's projector refresh runs a shard-local ``all_gather → SVD →
+    slice`` (the ColossalAI ``distributed_galore`` schedule) so the new
+    projectors are born sharded, and the steady family math is
+    leading-axis elementwise/batched — GSPMD partitions it from the in/out
+    shardings alone.
+
+    ``member_spec(path, leaf)`` gives the resolved spec of each param leaf
+    (the GSPMD step over FSDP-sharded params).  A family whose members all
+    shard one matrix dim on ``axis`` then keeps its gradient, parameters and
+    update in the members' own layout (:func:`family_layout`): projection
+    contracts each member's local rows and reduce-scatters only the rank-r
+    partial sums onto the stack-sharded moments, and back-projection gathers
+    the rank-r result and multiplies by the local projector rows, so no
+    full-size gradient or update crosses the mesh in a steady step.
+
+    ``mesh`` may be a concrete :class:`jax.sharding.Mesh` or an
+    ``AbstractMesh`` (the collective auditor traces device-free)."""
     prev = getattr(_FAMILY_SHARDING, "ctx", None)
-    _FAMILY_SHARDING.ctx = (mesh, axis)
+    _FAMILY_SHARDING.ctx = FamilySharding(mesh, axis, member_spec)
     try:
         yield
     finally:
         _FAMILY_SHARDING.ctx = prev
 
 
-def active_family_sharding():
-    """The active ``(mesh, axis)`` family-sharding declaration, or None."""
+def active_family_sharding() -> Optional[FamilySharding]:
+    """The active family-sharding declaration, or None."""
     return getattr(_FAMILY_SHARDING, "ctx", None)
 
 
 def family_shard_count(shard_ctx) -> int:
-    """Shard count of a ``(mesh, axis)`` context (1 when ctx is None)."""
+    """Shard count of a family-sharding context (1 when ctx is None)."""
     if shard_ctx is None:
         return 1
-    mesh, axis = shard_ctx
-    return int(mesh.shape[axis])
+    return int(shard_ctx.mesh.shape[shard_ctx.axis])
+
+
+class FamilyLayout(NamedTuple):
+    """How one family lies on the family-sharding axis when its members are
+    FSDP-sharded there: ``dims[j]`` is the matrix dim (``"m"`` rows, ``"n"``
+    columns) member ``j``'s leaf shards, and ``proj`` the projector's layout
+    — its ``s`` dim (``"m"`` on the left side, ``"n"`` on the right) when
+    some member shards that dim, else ``"stack"`` or ``"replicated"`` as the
+    stack rule gives."""
+
+    dims: tuple
+    proj: str
+
+
+def family_layout(fam, specs, axis: str, n_shards: int) -> Optional[FamilyLayout]:
+    """The member layout of ``fam`` from its members' resolved param
+    ``specs`` (in member order), or None — the stack rule — unless every
+    member shards exactly one of its two matrix dims, on ``axis`` alone."""
+    if n_shards <= 1:
+        return None
+    on = lambda ax: ax == axis or ax == (axis,)
+    dims = []
+    for spec in specs:
+        *lead, sm, sn = tuple(spec) + (None,) * (len(fam.member_fs.lead)
+                                                 + 2 - len(spec))
+        if any(ax is not None for ax in lead):
+            return None
+        if on(sm) and sn is None:
+            dims.append("m")
+        elif on(sn) and sm is None:
+            dims.append("n")
+        else:
+            return None
+    s_dim = "m" if fam.fs.side == "left" else "n"
+    return FamilyLayout(tuple(dims),
+                        s_dim if s_dim in dims else _stack_rule(fam, n_shards))
+
+
+def projector_layout(fam, lay: Optional[FamilyLayout], n_shards: int) -> str:
+    """Where ``fam``'s projector lies: ``"m"``/``"n"`` (its s dim),
+    ``"stack"`` or ``"replicated"``."""
+    if lay is not None:
+        return lay.proj
+    return _stack_rule(fam, n_shards)
+
+
+def _stack_rule(fam, n_shards: int) -> str:
+    return "stack" if stack_shardable(fam.fs.L, n_shards) else "replicated"
+
+
+def _plan_layouts(plan, leaves, paths, axis, n_shards, member_spec) -> list:
+    return [family_layout(fam, [member_spec(paths[i], leaves[i])
+                                for i in fam.members], axis, n_shards)
+            for fam in plan.families]
+
+
+def _flat_paths(treedef, params) -> list:
+    """'/'-joined param path per flat leaf (None kept in place)."""
+    return treedef.flatten_up_to(tree_paths(params))
+
+
+def family_layouts(transform: Transform, state: PyTree, params: PyTree,
+                   axis: str, n_shards: int, member_spec: Callable) -> list:
+    """``(LowRankState, FamilyPlan, [FamilyLayout | None])`` for every
+    family-stacked ``lowrank()`` node of ``transform``, walking its
+    composition (``chain_info``) in step with ``state`` and the params each
+    node sees — the same plan and rule the node's update applies under
+    ``family_sharding(mesh, axis, member_spec)``."""
+    out = []
+
+    def walk(info, st, params):
+        kind = info.get("kind")
+        if kind == "multi_transform" and info.get("label_fn") is not None:
+            labels = info["label_fn"](params)
+            inner = getattr(st, "inner", None) or {}
+            for name, branch in info.get("branches", {}).items():
+                if name in inner:
+                    walk(branch, inner[name], jax.tree_util.tree_map(
+                        lambda p, l, name=name: p if l == name else None,
+                        params, labels))
+        elif kind == "chain" and isinstance(st, tuple):
+            for stage, sub in zip(info.get("stages", []), st):
+                walk(stage, sub, params)
+        elif (kind == "lowrank" and info.get("fuse_families")
+              and isinstance(st, LowRankState)):
+            leaves, treedef = jax.tree_util.tree_flatten(params, is_leaf=_IS_NONE)
+            plan = build_family_plan(leaves, info.get("rank"))
+            out.append((st, plan, _plan_layouts(
+                plan, leaves, _flat_paths(treedef, params), axis, n_shards,
+                member_spec)))
+
+    walk(chain_info(transform), state, params)
+    return out
+
+
+def _member_pspec(fam, dim: str, axis: str):
+    lead = (None,) * len(fam.member_fs.lead)
+    return _P(*lead, axis, None) if dim == "m" else _P(*lead, None, axis)
+
+
+def _proj_pspec(lay: FamilyLayout, axis: str):
+    return {"stack": _P(axis), "replicated": _P()}.get(lay.proj,
+                                                       _P(None, axis, None))
+
+
+def _member_projectors(fam, lay: FamilyLayout, p_loc, axis: str):
+    """Inside the shard_map: ``(j, contracting, P_j)`` per member, with its
+    projector rows as it needs them.  A contracting member shards the dim
+    the projector spans, so it takes the local ``s`` rows and its projection
+    sums partial products over chips; a free member shards the other dim
+    and takes the whole ``s`` span (gathered: rank-r sized)."""
+    L = fam.seg.member_L
+    s_dim = "m" if fam.fs.side == "left" else "n"
+    if lay.proj == "stack":
+        p_loc = jax.lax.all_gather(p_loc, axis, axis=0, tiled=True)
+    for j, dim in enumerate(lay.dims):
+        pj = p_loc[j * L:(j + 1) * L]
+        if dim != s_dim and lay.proj in ("m", "n"):
+            pj = jax.lax.all_gather(pj, axis, axis=1, tiled=True)
+        yield j, dim == s_dim, pj
+
+
+def _layout_project(fam, lay: FamilyLayout, p, parts, impl: str,
+                    pad_rank_to: int):
+    """PᵀG (left) / G P (right) of a family whose gradient ``parts`` lie in
+    the members' layout: each chip projects its local rows of every member
+    (one kernel call per member: stacking them first would copy the whole
+    gradient) and the rank-r partial sums are reduce-scattered onto the
+    stack layout of the projected moments (summed whole where the stack
+    does not divide)."""
+    ctx = active_family_sharding()
+    mesh, axis, n = ctx.mesh, ctx.axis, family_shard_count(ctx)
+    fs, L = fam.fs, fam.seg.member_L
+    free_ax = -1 if fs.side == "left" else -2   # result dim a free member shards
+    low_sharded = stack_shardable(fs.L, n)
+
+    def body(p_loc, *g_loc):
+        k = jax.lax.axis_index(axis)
+        out = []
+        for j, contracting, pj in _member_projectors(fam, lay, p_loc, axis):
+            r = _dispatch().project(
+                pj, g_loc[j].reshape((L,) + g_loc[j].shape[-2:]),
+                side=fs.side, impl=impl, pad_rank_to=pad_rank_to)
+            if not contracting:   # this chip's slice of the free dim
+                full = list(r.shape)
+                full[free_ax] *= n
+                r = jax.lax.dynamic_update_slice_in_dim(
+                    jnp.zeros(full, r.dtype), r, k * r.shape[free_ax],
+                    axis=free_ax)
+            out.append(r)
+        partial = jnp.concatenate(out)
+        if low_sharded:
+            return jax.lax.psum_scatter(partial, axis, scatter_dimension=0,
+                                        tiled=True)
+        return jax.lax.psum(partial, axis)
+
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(_proj_pspec(lay, axis),) + tuple(
+            _member_pspec(fam, d, axis) for d in lay.dims),
+        out_specs=_P(axis) if low_sharded else _P(), check_vma=False,
+    )(p, *parts)
+
+
+def _layout_back(fam, lay: FamilyLayout, p, s, impl: str, pad_rank_to: int):
+    """Back-projection into the members' layout: the rank-r ``s`` is
+    gathered over the stack, and each chip multiplies it by its local
+    projector rows (or its slice of the free dim), so every member's update
+    is born in its leaf's FSDP layout."""
+    ctx = active_family_sharding()
+    mesh, axis, n = ctx.mesh, ctx.axis, family_shard_count(ctx)
+    fs, L = fam.fs, fam.seg.member_L
+    free_ax = -1 if fs.side == "left" else -2
+    low_sharded = stack_shardable(fs.L, n)
+
+    def body(p_loc, s_loc):
+        k = jax.lax.axis_index(axis)
+        s_all = (jax.lax.all_gather(s_loc, axis, axis=0, tiled=True)
+                 if low_sharded else s_loc)
+        out = []
+        for j, contracting, pj in _member_projectors(fam, lay, p_loc, axis):
+            sj = s_all[j * L:(j + 1) * L]
+            if not contracting:
+                c = sj.shape[free_ax] // n
+                sj = jax.lax.dynamic_slice_in_dim(sj, k * c, c, axis=free_ax)
+            u = _dispatch().back_project(pj, sj, side=fs.side, impl=impl,
+                                         pad_rank_to=pad_rank_to)
+            out.append(u.reshape(fam.member_fs.lead + u.shape[-2:]))
+        return tuple(out)
+
+    parts = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(_proj_pspec(lay, axis), _P(axis) if low_sharded else _P()),
+        out_specs=tuple(_member_pspec(fam, d, axis) for d in lay.dims),
+        check_vma=False,
+    )(p, s)
+    return MemberStack(fam, parts)
 
 
 # ---------------------------------------------------------------------------
@@ -1007,7 +1285,7 @@ def lowrank(
         replicated path would (same gradient, same keys → bit-identical
         values), and keeps only its own slice: the refreshed projectors are
         born sharded, no second collective to redistribute them."""
-        mesh, axis = shard_ctx
+        mesh, axis = shard_ctx.mesh, shard_ctx.axis
         loc = fam.fs.L // family_shard_count(shard_ctx)
 
         def body(g_loc, keys):
@@ -1021,17 +1299,58 @@ def lowrank(
             check_vma=False,
         )(g_stack, keys_proj)
 
-    def _refresh_projectors(fam, g_stack, keys_proj):
-        """Dispatch one family's projector refresh: sharded when a
-        family-sharding context is active and the stack divides the axis,
-        replicated otherwise (the non-divisible fallback keeps auditor
-        expectation and runtime consistent — both count only divisible
-        families as gathered)."""
+    def _layout_projectors(fam, lay, g, keys_proj, shard_ctx):
+        """The sharded refresh of a family in a member layout: one gather of
+        the family's gradient (each member from its own layout), each
+        member's projector computed from it as the replicated path computes
+        it, and each chip keeps its slice of the projector's layout (its
+        ``s`` rows, or its stack slice)."""
+        mesh, axis = shard_ctx.mesh, shard_ctx.axis
+        n = family_shard_count(shard_ctx)
+        mfs = fam.member_fs
+
+        def body(*args):
+            *g_loc, keys = args
+            # The barrier keeps each gather whole: without it the compiler
+            # folds the gather into every matmul that reads the gradient
+            # and gathers it again for each.
+            g_full = jax.lax.optimization_barrier([
+                jax.lax.all_gather(x, axis, tiled=True,
+                                   axis=x.ndim - (2 if d == "m" else 1))
+                for x, d in zip(g_loc, lay.dims)])
+            p_full = jnp.concatenate([
+                compute_projectors(projector, x, mfs.rank, keys[j], mfs.side,
+                                   subspace_iters).reshape(
+                    (fam.seg.member_L,) + proj_shape(mfs)[len(mfs.lead):])
+                for j, x in enumerate(g_full)])
+            if lay.proj == "replicated":
+                return p_full
+            dim = 0 if lay.proj == "stack" else 1
+            c = p_full.shape[dim] // n
+            return jax.lax.dynamic_slice_in_dim(
+                p_full, jax.lax.axis_index(axis) * c, c, axis=dim)
+
+        return jax.shard_map(
+            body, mesh=mesh,
+            in_specs=tuple(_member_pspec(fam, d, axis) for d in lay.dims)
+            + (_P(),),
+            out_specs=_proj_pspec(lay, axis), check_vma=False,
+        )(*g.parts, keys_proj)
+
+    def _refresh_projectors(fam, g, keys_proj, lay=None):
+        """Dispatch one family's projector refresh: in the members' layout
+        when they have one, sharded on the stack when a family-sharding
+        context is active and the stack divides the axis, replicated
+        otherwise (the non-divisible fallback keeps auditor expectation and
+        runtime consistent — both count only divisible families as
+        gathered)."""
         shard_ctx = active_family_sharding()
+        if lay is not None:
+            return _layout_projectors(fam, lay, g, keys_proj, shard_ctx)
         if shard_ctx is not None \
                 and stack_shardable(fam.fs.L, family_shard_count(shard_ctx)):
-            return _sharded_projectors(fam, g_stack, keys_proj, shard_ctx)
-        return _stacked_projectors(fam, g_stack, keys_proj)
+            return _sharded_projectors(fam, g, keys_proj, shard_ctx)
+        return _stacked_projectors(fam, g, keys_proj)
 
     def _probe_fresh(p_new, p_old, g32, fs, old_probe):
         """Refresh-boundary probe: the spectrum sketch, plus (telemetry)
@@ -1085,6 +1404,26 @@ def lowrank(
                         )
         return leaves, treedef, plan, g_leaves
 
+    def _layouts(params, treedef, leaves, plan):
+        """Each family's member layout under the active family sharding
+        (None per family without one)."""
+        ctx = active_family_sharding()
+        if ctx is None or ctx.member_spec is None:
+            return [None] * len(plan.families)
+        return _plan_layouts(plan, leaves, _flat_paths(treedef, params),
+                             ctx.axis, family_shard_count(ctx),
+                             ctx.member_spec)
+
+    def _family_stack(fam, lay, parts):
+        """A family's member arrays: stacked, or kept apart
+        (:class:`MemberStack`) in a member layout."""
+        ms = MemberStack(fam, parts)
+        return ms if lay is not None else ms.stacked()
+
+    def _family_grads(fam, lay, g_leaves):
+        return _family_stack(fam, lay, [g_leaves[i].astype(jnp.float32)
+                                        for i in fam.members])
+
     def init_fused(params: PyTree) -> LowRankState:
         leaves, _, plan, _ = _plan_leaves(params)
         projs = [jnp.zeros(proj_shape(fam.fs), jnp.float32)
@@ -1110,6 +1449,7 @@ def lowrank(
         base_key = jax.random.fold_in(jax.random.PRNGKey(seed), count)
 
         leaves, treedef, plan, g_leaves = _plan_leaves(params, updates)
+        lays = _layouts(params, treedef, leaves, plan)
 
         # Stacking the params costs a concat per family per step; only pay it
         # when the inner transform actually reads them (layerwise_unbias
@@ -1117,12 +1457,9 @@ def lowrank(
         # shapes, which ProjGrad.fs already carries).
         inner_wants_params = bool(getattr(inner.update, "wants_params", False))
         fam_msgs, fam_projs, fam_params, fam_probes = [], [], [], []
-        for fi, fam in enumerate(plan.families):
+        for fi, (fam, lay) in enumerate(zip(plan.families, lays)):
             with jax.named_scope("lowrank.project"):
-                g32 = stack_family(
-                    fam, [g if g is None else g.astype(jnp.float32)
-                          for g in g_leaves]
-                )
+                g32 = _family_grads(fam, lay, g_leaves)
             keys_proj, keys_samp = _family_keys(fam, base_key)
             if external_refresh:
                 p_proj = state.projs[fi]
@@ -1130,8 +1467,8 @@ def lowrank(
                 with jax.named_scope("lowrank.refresh"):
                     p_proj = jax.lax.cond(
                         refresh,
-                        lambda _, fam=fam, g32=g32, kp=keys_proj:
-                            _refresh_projectors(fam, g32, kp),
+                        lambda _, fam=fam, g32=g32, kp=keys_proj, lay=lay:
+                            _refresh_projectors(fam, g32, kp, lay),
                         lambda _, fi=fi: state.projs[fi],
                         None,
                     )
@@ -1139,8 +1476,8 @@ def lowrank(
                         fam_probes.append(jax.lax.cond(
                             refresh,
                             lambda _, p=p_proj, g=g32, fam=fam, fi=fi:
-                                _probe_fresh(p, state.projs[fi], g, fam.fs,
-                                             state.probes[fi]),
+                                _probe_fresh(p, state.projs[fi], _stacked(g),
+                                             fam.fs, state.probes[fi]),
                             lambda _, fi=fi: state.probes[fi],
                             None,
                         ))
@@ -1149,12 +1486,13 @@ def lowrank(
                 pad_rank_to=pad_rank_to, coeff=1.0,
                 reset=(refresh if (reset_on_refresh and not external_refresh) else None),
                 refresh=(False if external_refresh else refresh),
-                key=keys_samp, seg=fam.seg,
+                key=keys_samp, seg=fam.seg, lay=lay,
             ))
             fam_projs.append(p_proj)
             with jax.named_scope("lowrank.project"):
                 fam_params.append(
-                    stack_family(fam, leaves) if inner_wants_params else None
+                    _family_stack(fam, lay, [leaves[i] for i in fam.members])
+                    if inner_wants_params else None
                 )
 
         if telemetry and not external_refresh:
@@ -1172,12 +1510,14 @@ def lowrank(
         for fam, msg, o, w in zip(plan.families, fam_msgs, inner_out, fam_params):
             if isinstance(o, FullUpdate):
                 with jax.named_scope("lowrank.back_project"):
-                    parts = unstack_family(fam, o.u)
+                    parts = _members(fam, o.u)
                 for i, part in zip(fam.members, parts):
                     out_leaves[i] = part
             elif fused_epilogue:
                 if w is None:
                     w = lambda fam=fam: stack_family(fam, leaves)
+                elif isinstance(w, MemberStack):
+                    w = w.stacked
                 for j, i in enumerate(fam.members):
                     out_leaves[i] = PendingBack(
                         p=msg.p, s=o, w=w, fs=fam.fs,
@@ -1186,9 +1526,9 @@ def lowrank(
                         member_lead=fam.member_fs.lead,
                     )
             else:
-                parts = msg.back(o)
+                parts = msg.back_members(o)
                 with jax.named_scope("lowrank.back_project"):
-                    parts = unstack_family(fam, parts)
+                    parts = _members(fam, parts)
                 for i, part in zip(fam.members, parts):
                     out_leaves[i] = part
 
@@ -1207,19 +1547,17 @@ def lowrank(
         refresh_now = (count - 1) % period == 0
         base_key = jax.random.fold_in(jax.random.PRNGKey(seed), count)
 
-        _, _, plan, g_leaves = _plan_leaves(params, grads)
+        leaves, treedef, plan, g_leaves = _plan_leaves(params, grads)
+        lays = _layouts(params, treedef, leaves, plan)
 
         new_projs, msgs, new_probes = [], [], []
-        for fi, fam in enumerate(plan.families):
-            g32 = stack_family(
-                fam, [g if g is None else g.astype(jnp.float32)
-                      for g in g_leaves]
-            )
+        for fi, (fam, lay) in enumerate(zip(plan.families, lays)):
+            g32 = _family_grads(fam, lay, g_leaves)
             keys_proj, keys_samp = _family_keys(fam, base_key)
             p_new = jax.lax.cond(
                 refresh_now,
-                lambda _, fam=fam, g32=g32, kp=keys_proj:
-                    _refresh_projectors(fam, g32, kp),
+                lambda _, fam=fam, g32=g32, kp=keys_proj, lay=lay:
+                    _refresh_projectors(fam, g32, kp, lay),
                 lambda _, fi=fi: state.projs[fi],
                 None,
             )
@@ -1228,7 +1566,7 @@ def lowrank(
                 new_probes.append(jax.lax.cond(
                     refresh_now,
                     lambda _, p=p_new, g=g32, fam=fam, fi=fi:
-                        _probe_fresh(p, state.projs[fi], g, fam.fs,
+                        _probe_fresh(p, state.projs[fi], _stacked(g), fam.fs,
                                      state.probes[fi]),
                     lambda _, fi=fi: state.probes[fi],
                     None,
@@ -1598,7 +1936,7 @@ def layerwise_unbias(
                         ).astype(jnp.int32)
                     idx2 = jnp.where(g.refresh, fresh, idx)
                 new_idx.append(idx2)
-                g_s = gather_blocks(g.g, idx2, fs)        # (gamma, m, n)
+                g_s = g.gather(idx2)                      # (gamma, m, n)
                 p_s = gather_blocks(g.p, idx2, fs)        # (gamma, s, r)
                 pptg = d.back_project(
                     p_s,
@@ -1608,7 +1946,7 @@ def layerwise_unbias(
                 )
                 resid = g_s - c_comp * pptg
                 full_upds.append(c_full * resid)
-                full_params.append(gather_blocks(p, idx2, fs))
+                full_params.append(_gather_blocks(p, idx2, fs))
 
         low_out, new_low = base.update(
             jax.tree_util.tree_unflatten(treedef, low_upds), state.low, params
@@ -1634,13 +1972,10 @@ def layerwise_unbias(
                 continue
             fs = g.fs
             g_f, q, *_ = _coeffs(fs, g.seg)
-            if q < 1.0:
-                u = g.back(lo)
-            else:
-                u = jnp.zeros(fs.lead + (fs.m, fs.n), jnp.float32)
+            u = g.back_members(lo) if q < 1.0 else g.full_zeros()
             if g_f > 0:
                 with jax.named_scope("unbias.full_slots"):
-                    u = scatter_blocks(u, idx2, fo, fs)
+                    u = _scatter_blocks(u, idx2, fo, fs)
             outs.append(FullUpdate(u))
 
         return (

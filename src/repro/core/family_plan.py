@@ -31,7 +31,12 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from .lowrank_common import FamilyShape, family_shape
+from .lowrank_common import (
+    FamilyShape,
+    family_shape,
+    gather_blocks,
+    scatter_blocks,
+)
 
 
 class StackSeg(NamedTuple):
@@ -124,9 +129,65 @@ def stack_family(fam: Family, leaves: list) -> jax.Array:
     Row-major, so member ``j``'s blocks occupy rows
     ``[j*member_L, (j+1)*member_L)`` in unravel order — matching
     :func:`jax.numpy.unravel_index` on the member's own lead dims."""
-    parts = jnp.stack([leaves[i] for i in fam.members])
-    return parts.reshape((fam.seg.members * fam.seg.member_L,)
-                         + parts.shape[1 + len(fam.member_fs.lead):])
+    return _stack_parts(fam, [leaves[i] for i in fam.members])
+
+
+def _stack_parts(fam: Family, parts: list) -> jax.Array:
+    stacked = jnp.stack(parts)
+    return stacked.reshape((fam.seg.members * fam.seg.member_L,)
+                           + stacked.shape[1 + len(fam.member_fs.lead):])
+
+
+class MemberStack:
+    """A family stack kept as its members: ``parts[j]`` is member ``j``'s
+    ``(*member_lead, a, b)`` array, in the layout its own leaf has.
+
+    Stands in for the stacked ``(L, a, b)`` array where stacking would move
+    data between chips: under an FSDP mesh the members of one family may
+    shard different dims (``wq`` its rows, ``wo`` its columns), and no single
+    layout of the stack holds them all in place.  Block indices are the
+    stack's own (member ``j``'s blocks are ``[j*member_L, (j+1)*member_L)``),
+    so :meth:`gather` / :meth:`scatter` take the per-member sampled indices
+    ``layerwise_unbias`` keeps, member ``j``'s ``g_f`` entries at
+    ``[j*g_f, (j+1)*g_f)``."""
+
+    __slots__ = ("fam", "parts")
+
+    def __init__(self, fam: Family, parts: list):
+        self.fam = fam
+        self.parts = list(parts)
+
+    def stacked(self) -> jax.Array:
+        """The ``(L, a, b)`` stack (moves data when the layouts differ)."""
+        return _stack_parts(self.fam, self.parts)
+
+    def map(self, fn) -> "MemberStack":
+        return MemberStack(self.fam, [fn(x) for x in self.parts])
+
+    def __mul__(self, f) -> "MemberStack":
+        return self.map(lambda x: x * f)
+
+    __rmul__ = __mul__
+
+    def _split(self, idx: jax.Array):
+        """Per member: its slice of ``idx`` made local to the member."""
+        g_f = int(idx.shape[0]) // self.fam.seg.members
+        L = self.fam.seg.member_L
+        return [(j, slice(j * g_f, (j + 1) * g_f),
+                 idx[j * g_f:(j + 1) * g_f] - j * L)
+                for j in range(self.fam.seg.members)]
+
+    def gather(self, idx: jax.Array) -> jax.Array:
+        """``(len(idx), a, b)``: the stack's blocks ``idx``."""
+        return jnp.concatenate([
+            gather_blocks(self.parts[j], loc, self.fam.member_fs)
+            for j, _, loc in self._split(idx)])
+
+    def scatter(self, idx: jax.Array, vals: jax.Array) -> "MemberStack":
+        """The stack with its blocks ``idx`` set to ``vals``."""
+        return MemberStack(self.fam, [
+            scatter_blocks(self.parts[j], loc, vals[sl], self.fam.member_fs)
+            for j, sl, loc in self._split(idx)])
 
 
 def unstack_family(fam: Family, stacked: jax.Array) -> list[jax.Array]:

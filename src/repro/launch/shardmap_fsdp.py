@@ -54,14 +54,18 @@ def make_shardmap_train_step(
 
     ``shard_state=True`` (ZeRO-style, requires a ``fuse_families=True``
     optimizer): family-stacked projectors and projected moments partition on
-    ``data_axis`` along the member-stack dim.  The steady-state collective
+    ``data_axis`` along the member-stack dim (``family_state_sharding``).
+    That layout is right here because the params and gradients are
+    replicated, so slicing a stack moves nothing: the steady-state collective
     schedule is UNCHANGED — still exactly one reduce-dtype gradient psum plus
     one loss pmean, zero gathers (the per-family optimizer math is
     leading-axis-parallel, so GSPMD partitions it from the state shardings
     alone); the only addition is one cond-gated ``all_gather`` per shardable
     family at projector-refresh boundaries, re-materializing the full stacked
     gradient for the SVD before the new projectors are sliced back out
-    sharded (see ``combinators.family_sharding``).
+    sharded (see ``combinators.family_sharding``).  The Trainer's GSPMD step
+    over FSDP-sharded params lays the projectors out by the members instead
+    (``sharding.opt_state_sharding``).
     """
     cfg = model.cfg
 

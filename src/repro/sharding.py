@@ -13,6 +13,7 @@ Param rules (DESIGN.md §5) are path-based so any pytree layout works.
 from __future__ import annotations
 
 import contextlib
+import functools
 import re
 import threading
 from typing import Any, Optional, Sequence
@@ -146,6 +147,13 @@ def spec_for_param(path: str, p: Any) -> tuple[Optional[str], ...]:
     return (None,) * (ndim - 2) + ("fsdp", None)
 
 
+def param_spec(path: str, p: Any, mesh: Mesh) -> P:
+    """The resolved spec of the param at ``path`` on ``mesh``: its rule,
+    with the axes its dims do not divide dropped."""
+    return validate_spec(p.shape, resolve_spec(spec_for_param(path, p), mesh),
+                         mesh)
+
+
 def param_specs(params: Any) -> Any:
     """Pytree of logical specs matching ``params``."""
     from repro.core.api import tree_paths  # local import to avoid cycles
@@ -161,10 +169,7 @@ def named_sharding_tree(params: Any, mesh: Mesh) -> Any:
 
     paths = tree_paths(params)
     return jax.tree_util.tree_map(
-        lambda path, p: NamedSharding(
-            mesh,
-            validate_spec(p.shape, resolve_spec(spec_for_param(path, p), mesh), mesh),
-        ),
+        lambda path, p: NamedSharding(mesh, param_spec(path, p, mesh)),
         paths,
         params,
     )
@@ -188,11 +193,8 @@ def per_shard_bytes(tree: Any, mesh: Mesh) -> int:
         for d in x.shape:
             nelem *= int(d)
         nbytes = nelem * jax.numpy.dtype(x.dtype).itemsize
-        spec = validate_spec(x.shape,
-                             resolve_spec(spec_for_param(path, x), mesh),
-                             mesh)
         shards = 1
-        for ax in spec:
+        for ax in param_spec(path, x, mesh):
             shards *= _axis_size(ax, mesh)
         total += nbytes // max(shards, 1)
     return total
@@ -224,13 +226,16 @@ def _family_shardable(x: Any, n_shards: int) -> bool:
 
 def family_state_sharding(opt_state: Any, mesh: Mesh,
                           axis: str = "data") -> Any:
-    """ZeRO-style sharding tree for a ``fuse_families=True`` optimizer state:
-    every family-stacked low-rank leaf (projectors, projected moments,
-    whatever the inner transform allocated per family) partitions on mesh
-    ``axis`` along its leading stack dim — members of a family land on
-    different shards — and everything else stays replicated, exactly like the
-    pure-DP shard_map step.  Families whose stack doesn't divide the axis
-    fall back to replicated (mirroring the runtime refresh fallback in
+    """ZeRO-style sharding tree for a ``fuse_families=True`` optimizer state
+    whose params are replicated (the pure-DP ``shard_map`` step): every
+    family-stacked low-rank leaf (projectors, projected moments, whatever the
+    inner transform allocated per family) partitions on mesh ``axis`` along
+    its leading stack dim — members of a family land on different shards —
+    and everything else stays replicated.  With replicated members, slicing
+    a stack is free, so this is the whole rule there; the GSPMD step over
+    FSDP-sharded params lays the projectors out by the members instead (see
+    :func:`opt_state_sharding`).  Families whose stack doesn't divide the
+    axis fall back to replicated (mirroring the runtime refresh fallback in
     ``combinators``)."""
     n = _axis_size(axis, mesh)
     fam_ids = _family_stack_leaf_ids(opt_state)
@@ -268,7 +273,8 @@ def family_state_bytes(opt_state: Any, n_shards: int) -> tuple[int, int]:
 
 
 def opt_state_sharding(opt_state: Any, mesh: Mesh, *,
-                       family_axis: Optional[str] = None) -> Any:
+                       family_axis: Optional[str] = None,
+                       optimizer: Any = None, params: Any = None) -> Any:
     """Sharding for optimizer states.  State leaves live under the param path
     they belong to (e.g. families/blocks/attn/wq/r_low), so the param rules
     apply directly; full-shape moments inherit the param's exact spec, and
@@ -276,20 +282,38 @@ def opt_state_sharding(opt_state: Any, mesh: Mesh, *,
 
     With ``family_axis`` (the ZeRO-sharded fused step), family-stacked
     low-rank leaves instead partition on that axis along their leading stack
-    dim — see :func:`family_state_sharding` for the rule."""
+    dim, as :func:`family_state_sharding` lays them out.  Given the
+    ``optimizer`` and the ``params`` it updates, a family's projector
+    ``(L, s, r)`` follows its members: where a member's param spec shards the
+    dim the projector spans (``s``: rows ``m`` on the left side, columns
+    ``n`` on the right), the projector shards ``s`` on ``family_axis``, so
+    the step projects and back-projects each member in its own FSDP layout
+    (``combinators.family_layout`` is the rule, read by the step too).  The
+    projected moments and full-rank slots keep the stack dim; families whose
+    members are replicated keep it for the projector too."""
     from repro.core.api import tree_paths
 
     paths = tree_paths(opt_state)
     fam_ids = _family_stack_leaf_ids(opt_state) if family_axis else set()
     fam_n = _axis_size(family_axis, mesh) if family_axis else 1
+    by_members = set()  # ids of projectors sharded on their s dim
+    if fam_ids and fam_n > 1 and optimizer is not None and params is not None:
+        from repro.core.combinators import family_layouts
+
+        for st, _, lays in family_layouts(
+                optimizer, opt_state, params, family_axis, fam_n,
+                functools.partial(param_spec, mesh=mesh)):
+            by_members.update(id(proj) for proj, lay in zip(st.projs, lays)
+                              if lay is not None and lay.proj in ("m", "n"))
 
     def leaf_sharding(path, x):
+        if id(x) in by_members:
+            return NamedSharding(mesh, P(None, family_axis, None))
         if family_axis and id(x) in fam_ids and fam_n > 1 \
                 and _family_shardable(x, fam_n):
             return NamedSharding(mesh, P(family_axis))
         if not hasattr(x, "ndim") or x.ndim <= 1:
             return NamedSharding(mesh, P())
-        spec = resolve_spec(spec_for_param(path, x), mesh)
-        return NamedSharding(mesh, validate_spec(x.shape, spec, mesh))
+        return NamedSharding(mesh, param_spec(path, x, mesh))
 
     return jax.tree_util.tree_map(leaf_sharding, paths, opt_state)

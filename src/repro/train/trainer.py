@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import os
 import statistics
 import time
@@ -42,7 +43,12 @@ from repro.core.rank_policy import RankPolicyController
 from repro.data import DataConfig, build_stream
 from repro.launch.steps import make_train_step
 from repro.models.transformer import Model
-from repro.sharding import named_sharding_tree, opt_state_sharding, use_mesh
+from repro.sharding import (
+    named_sharding_tree,
+    opt_state_sharding,
+    param_spec,
+    use_mesh,
+)
 from repro.telemetry import (
     JsonlSink,
     MemorySink,
@@ -142,9 +148,11 @@ class Trainer:
         self.mesh = mesh
         self.microbatches = microbatches
         # ZeRO-style sharded projected state: family-stacked low-rank leaves
-        # partition over the data axis (combinators.family_sharding routes
-        # the projector refresh through the boundary all_gather).  Only
-        # meaningful with a mesh and the fused family layout.
+        # partition over the data axis, and each family's projector follows
+        # its members' FSDP layout (combinators.family_sharding, given the
+        # param rules; sharding.opt_state_sharding lays the state out by the
+        # same rule).  Only meaningful with a mesh and the fused family
+        # layout.
         self.shard_state = bool(
             getattr(opt_cfg, "shard_state", False)
             and opt_cfg.fuse_families and mesh is not None)
@@ -250,13 +258,14 @@ class Trainer:
             from repro.core.combinators import family_sharding
 
             mesh, axis = self.mesh, self._family_axis
+            member_spec = functools.partial(param_spec, mesh=mesh)
             inner_step = step_fn
 
             def step_fn(*args, _inner=inner_step):
                 # entered at TRACE time: the fused lowrank path sees the
-                # context and emits the sharded (all_gather-at-boundary)
-                # projector refresh for shardable families
-                with family_sharding(mesh, axis):
+                # context, keeps each family in its members' FSDP layout and
+                # emits the sharded (all_gather-at-boundary) projector refresh
+                with family_sharding(mesh, axis, member_spec):
                     return _inner(*args)
 
         self._step_fn = step_fn
@@ -272,9 +281,41 @@ class Trainer:
         params = jax.jit(self.model.init, out_shardings=named_sharding_tree(
             params_abs, self.mesh))(key)
         opt_abs = jax.eval_shape(self.optimizer.init, params_abs)
-        opt_state = jax.jit(self.optimizer.init, out_shardings=opt_state_sharding(
-            opt_abs, self.mesh, family_axis=self._family_axis))(params)
+        opt_state = jax.jit(self.optimizer.init, out_shardings=self._opt_sharding(
+            opt_abs, params_abs))(params)
         return params, opt_state
+
+    def _opt_sharding(self, opt_state, params):
+        """The optimizer state's shardings on the mesh (the family-stacked
+        state laid out by the members of ``params``, under ``shard_state``)."""
+        return opt_state_sharding(opt_state, self.mesh,
+                                  family_axis=self._family_axis,
+                                  optimizer=self.optimizer, params=params)
+
+    def _family_layout_event(self, params) -> None:
+        """Start-up record of where each family's projector lies on the
+        mesh: its ``s`` dim (``m``/``n``, following the members' FSDP
+        layout), the ``stack`` dim, or ``replicated``."""
+        from repro.core.combinators import family_layouts, projector_layout
+
+        axis = self._family_axis
+        n = self.mesh.shape[axis]
+        opt_abs = jax.eval_shape(self.optimizer.init, params)
+        rows = [
+            {"family": f"{fam.member_fs.m}x{fam.member_fs.n}"
+                       f"r{fam.member_fs.rank}x{fam.seg.members}",
+             "projector": projector_layout(fam, lay, n),
+             "members": "".join(lay.dims) if lay is not None else None}
+            for _, plan, lays in family_layouts(
+                self.optimizer, opt_abs, params, axis, n,
+                functools.partial(param_spec, mesh=self.mesh))
+            for fam, lay in zip(plan.families, lays)
+        ]
+        self.tele.event(
+            "family_layout",
+            f"audit[{self.opt_cfg.name}]: projector layout over {axis}={n}: "
+            + ", ".join(f"{r['family']} {r['projector']}" for r in rows),
+            layouts=rows)
 
     def _jit_step(self, params, opt_state):
         # One jitted step per rank assignment; without a controller there is
@@ -288,8 +329,7 @@ class Trainer:
             jitted = jax.jit(self._step_fn, donate_argnums=(0, 1))
         else:
             psh = named_sharding_tree(params, self.mesh)
-            osh = opt_state_sharding(opt_state, self.mesh,
-                                     family_axis=self._family_axis)
+            osh = self._opt_sharding(opt_state, params)
             jitted = jax.jit(
                 self._step_fn,
                 in_shardings=(psh, osh) + (None,) * (n_in - 2),
@@ -374,10 +414,8 @@ class Trainer:
         ``opt_state_sharding`` at jit time.  No-op without a mesh."""
         if self.mesh is None:
             return opt_state
-        return jax.device_put(
-            opt_state,
-            opt_state_sharding(opt_state, self.mesh,
-                               family_axis=self._family_axis))
+        params = jax.eval_shape(self.model.init, jax.random.PRNGKey(0))
+        return jax.device_put(opt_state, self._opt_sharding(opt_state, params))
 
     def _restore_shardings(self, params, opt_state):
         """Shardings to re-apply on checkpoint restore (None off-mesh):
@@ -387,8 +425,7 @@ class Trainer:
         if self.mesh is None:
             return None
         return (named_sharding_tree(params, self.mesh),
-                opt_state_sharding(opt_state, self.mesh,
-                                   family_axis=self._family_axis))
+                self._opt_sharding(opt_state, params))
 
     def _ckpt_extra(self) -> Optional[dict]:
         if self.rank_ctrl is None:
@@ -516,6 +553,8 @@ class Trainer:
                             f"{f['op']}{tuple(f['shape'])}"
                             for f in xc["fallbacks"]),
                         fallbacks=xc["fallbacks"])
+            if self.shard_state:
+                self._family_layout_event(params)
             if self.mesh is not None:
                 # Mesh run: also verify the jitted step's donation wiring on
                 # the lowered module (donated params/opt_state must alias
