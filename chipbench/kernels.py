@@ -17,8 +17,11 @@ FLOPS = {
         "gram": lambda x: work.ns_gram(*x),
         "poly_matmul_axpy": lambda a, x: work.ns_apply(*x),
     },
+    # the projection PᵀG: fused with the momentum update, or alone (the
+    # full-rank slots' residual, and each chip's share of a member on a mesh)
     "lowrank_update": {
         "lowrank_update_batched": lambda p, g, r: work.lowrank_update(*g, p[-1]),
+        "project_batched": lambda p, g: work.project(*g, p[-1]),
     },
 }
 

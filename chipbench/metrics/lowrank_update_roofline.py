@@ -1,5 +1,6 @@
-"""Projected-momentum update kernel: the least time its work needs at the
-called shapes over the device time of its events, per steady step."""
+"""Low-rank projection kernels (``PᵀG`` fused with the momentum update,
+and ``PᵀG`` alone): the least time their work needs at the called shapes
+over the device time of their events, per steady step."""
 from chipbench import kernels
 
 

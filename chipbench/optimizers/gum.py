@@ -70,7 +70,12 @@ def projector(kind, g, rank, key, side, iters=2):
     if side == "right":
         g = jnp.swapaxes(g, -1, -2)
     if kind == "svd":
-        return jnp.linalg.svd(g, full_matrices=False)[0][..., :rank]
+        # G = U S Vᵀ gives G Gᵀ = U S² Uᵀ: the leading eigenvectors of the
+        # short side's Gram matrix span G's leading left singular subspace
+        # (the same subspace in another basis; the check compares norms
+        # that no basis changes), and compile in less time than G's SVD
+        vecs = jnp.linalg.eigh(g @ jnp.swapaxes(g, -1, -2))[1]
+        return vecs[..., ::-1][..., :rank]
     if kind == "subspace":
         y = g @ jax.random.normal(key, g.shape[:-2] + (g.shape[-1], rank), jnp.float32)
         for _ in range(iters):

@@ -113,12 +113,13 @@ def lower(cell, abstract_params, devices, dtype=jnp.float32):
 
 
 def run(cell, abstract_params, seed: int, steps: int = 3, dtype=jnp.float32,
-        batch_rows=None, devices=None) -> dict:
+        batch_rows=None, devices=None, phases=None) -> dict:
     """Losses of ``steps`` steps, the first gradient as the optimizer keeps
     it, the raw first gradient per leaf, and each leaf's change after the
-    last step.  ``batch_rows`` keeps only the first rows of every batch (a
-    planted fault: part of the batch left out, the mean taken over the
-    rest; one program serves every ``batch_rows``).  On more than one chip the
+    last step.  ``phases`` (``run.Phases``) is told where each phase of the
+    reference ends.  ``batch_rows`` keeps only the first rows of every batch (a planted
+    fault: part of the batch left out, the mean taken over the rest; one
+    program serves every ``batch_rows``).  On more than one chip the
     weights and state are divided over ``devices`` so that they fit; the
     arithmetic is that of one chip."""
     pr = _program(cell, abstract_params, dtype, devices)
@@ -126,20 +127,28 @@ def run(cell, abstract_params, seed: int, steps: int = 3, dtype=jnp.float32,
     make_params, optim, opt_cfg = pr["make_params"], pr["optim"], pr["opt_cfg"]
     pkey = inputs.stream_key(seed, inputs.PARAMS_STREAM)
     tkey = inputs.stream_key(seed, inputs.TOKENS_STREAM)
+    mark = phases.mark if phases is not None else (lambda name: None)
     with jax.default_matmul_precision(pr["precision"]):
         flat = jax.jit(lambda k: _placed(_flat(make_params(k)), pr["flat_sh"]))(pkey)
         state = jax.jit(lambda f: optim.init(f, opt_cfg, dtype),
                         out_shardings=pr["state_sh"])(flat)
+        jax.block_until_ready(state)
+        mark("reference_make")
+        step = pr["step"].lower(flat, state, jnp.int32(1), tkey, jnp.int32(0),
+                                keep).compile()
+        mark("reference_compile")
         losses, raw, first = [], None, None
         for s in range(steps):
-            flat, state, loss, r, f = pr["step"](flat, state, jnp.int32(s + 1), tkey,
-                                                 jnp.int32(s), keep)
+            flat, state, loss, r, f = step(flat, state, jnp.int32(s + 1), tkey,
+                                           jnp.int32(s), keep)
             losses.append(float(loss))
             if s == 0:
                 raw, first = jax.device_get(r), jax.device_get(f)
         del state
+        mark("reference_steps")
         change = jax.device_get(jax.jit(lambda f, k: change_norms(
             f, lambda key: _placed(_flat(make_params(key)), pr["flat_sh"]), k))(flat, pkey))
+        mark("reference_change")
     return {"losses": losses, "first": {k: float(v) for k, v in first.items()},
             "families": optim.families(pr["flat_abs"]),
             "raw": {k: float(v) for k, v in raw.items()},

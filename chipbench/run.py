@@ -3,13 +3,16 @@
     python3 chipbench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 One process: make the weights and the optimizer state on the device from the
-seed, compile the Trainer's jitted train step ahead of time, run the cell's
-first steps (step 0 is a projector refresh and is timed as the refresh
-sample), warm up, then measure steady steps for ``--seconds``.  Afterwards the
-program's state is freed and the plain reference recomputes the first steps;
-``correct`` says whether the program stayed within the cell's limits.  The
-last line of standard output is the result as JSON.  With ``--trace 1`` the
-window runs under the profiler and the result carries the per-layer metrics.
+seed, compile the Trainer's jitted train step ahead of time, call it once
+and drop the result (a program's first call is slower than the rest), run
+the cell's first steps from the seed again (step 0 is a projector refresh
+and is timed as the refresh sample), warm up, then measure steady steps for
+``--seconds``.  Afterwards the program's state is freed and the plain
+reference recomputes the first steps; ``correct`` says whether the program
+stayed within the cell's limits.  Each phase of the run is timed on the
+host clock and printed to standard error (``setup_phases``).  The last line
+of standard output is the result as JSON.  With ``--trace 1`` the window
+runs under the profiler and the result carries the per-layer metrics.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits non-zero
 and prints no result.
@@ -42,6 +45,21 @@ GIB = 1024 ** 3
 
 class NoChip(RuntimeError):
     pass
+
+
+class Phases:
+    """Host seconds of the phases of a run, in the order they ran: each
+    ``mark`` closes the phase that began at the one before (the first at
+    process start), so together they cover the whole run: the set-up up to
+    the window, the window, the per-layer reading and the reference."""
+
+    def __init__(self, start: float = _T0):
+        self.last, self.seconds = start, {}
+
+    def mark(self, name: str) -> None:
+        now = time.perf_counter()
+        self.seconds[name] = self.seconds.get(name, 0.0) + now - self.last
+        self.last = now
 
 
 def parse(argv):
@@ -117,7 +135,7 @@ def build_trainer(cell, devices):
 
 
 def measure(cell, seed: int, seconds: float, trace: bool, devices,
-            wrap_step=None) -> dict:
+            wrap_step=None, phases=None) -> dict:
     """Set up, run the first steps and the window; returns what was
     measured, the program's readings for the check, and the trace."""
     import jax
@@ -125,19 +143,21 @@ def measure(cell, seed: int, seconds: float, trace: bool, devices,
 
     from chipbench import check, inputs, period, reference
 
+    phases = phases or Phases()
+    phases.mark("start")
     trainer = build_trainer(cell, devices)
     abstract = jax.eval_shape(trainer.model.init, jax.random.PRNGKey(0))
     make_params = inputs.make_params_fn(abstract)
     pkey = inputs.stream_key(seed, inputs.PARAMS_STREAM)
     tkey = inputs.stream_key(seed, inputs.TOKENS_STREAM)
+    phases.mark("build")
     # The step is compiled on shapes; weights, state and tokens are then
     # made where the compiled step takes them (its shards on a mesh).
     compiled = trainer.lower_step(abstract).compile()
+    phases.mark("compile")
     p_sh, o_sh, b_sh = compiled.input_shardings[0][:3]
     make = jax.jit(make_params, out_shardings=p_sh)
     init = jax.jit(trainer.optimizer.init, out_shardings=o_sh)
-    params = make(pkey)
-    opt_state = init(params)
     step_fn = compiled if wrap_step is None else wrap_step(compiled)
     make_tokens = jax.jit(inputs.make_tokens_fn(
         cell.traffic, int(cell.config["vocab_size"])), out_shardings=b_sh["tokens"])
@@ -146,14 +166,20 @@ def measure(cell, seed: int, seconds: float, trace: bool, devices,
     # the initial weights made again, in the weights' own shards
     change_norms = jax.jit(lambda p, k: reference.change_norms(
         p, lambda key: jax.lax.with_sharding_constraint(make_params(key), p_sh), k))
-    opt_bytes = max(_bytes_on(opt_state, d) for d in devices)
     mem = compiled.memory_analysis()
     hlo_text = compiled.as_text() if trace else ""
 
     per = int(cell.traffic["optimizer"]["period"])
     log = {"refresh": [], "steady": [], "attempted": 0, "failed": 0}
-    state = {"params": params, "opt": opt_state}
-    del params, opt_state
+    state = {}
+
+    def start():
+        """Weights and optimizer state made from the seed, in place of
+        whatever the job held (one state on the chips at a time)."""
+        state.clear()
+        state["params"] = make(pkey)
+        state["opt"] = init(state["params"])
+        jax.block_until_ready(state["opt"])
 
     def span(name):
         # host spans on the profiler's clock, for the idle-gap breakdown
@@ -179,30 +205,38 @@ def measure(cell, seed: int, seconds: float, trace: bool, devices,
         log["failed"] += not ok
         return loss
 
+    # The first call of a compiled program is slower than the rest, so one
+    # call goes first and its result is dropped: the check's step 0, from
+    # the seed again, is then the refresh sample.
+    start()
+    opt_bytes = max(_bytes_on(state["opt"], d) for d in devices)
+    phases.mark("make")
+    one(0)
+    phases.mark("first_call")
+    start()
+    phases.mark("make_again")
     # The check's first steps go through the window's own call and feed.
+    log.update(refresh=[], steady=[], attempted=0, failed=0)
     losses = [one(0)]
     first = {k: float(v) for k, v in jax.device_get(first_norms(state["opt"])).items()}
     losses += [one(1), one(2)]
+    phases.mark("check_steps")
     change = {k: float(v) for k, v in
               jax.device_get(change_norms(state["params"], pkey)).items()}
-    # The first call of a compiled program is slower than the rest, so the
-    # job starts again from the seed: its step 0 is the refresh sample.
-    state.clear()
-    state["params"] = make(pkey)
-    state["opt"] = init(state["params"])
-    log.update(refresh=[], steady=[], attempted=0, failed=0)
-    warm = int(cell.traffic["warmup_steps"])
-    for k in range(1 + warm):
-        one(k)
+    phases.mark("change_norms")
     refresh = log["refresh"]
+    k = 3 + int(cell.traffic["warmup_steps"])
+    for j in range(3, k):
+        one(j)
+        phases.mark(f"step{j}")
     log.update(refresh=[], steady=[], attempted=0, failed=0)
 
     trace_dir = os.path.join(WORK_DIR, "trace")
     if trace:
         shutil.rmtree(trace_dir, ignore_errors=True)
         jax.profiler.start_trace(trace_dir)
+        phases.mark("start_trace")
     t_window = time.perf_counter()
-    k = 1 + warm
     while True:  # at least one step
         one(k)
         k += 1
@@ -214,6 +248,7 @@ def measure(cell, seed: int, seconds: float, trace: bool, devices,
     peak = held_bytes(mem, devices)
     del state, step_fn, compiled
     gc.collect()
+    phases.mark("window")
     log["refresh"] = refresh + log["refresh"]
 
     tps = period.tokens_per_s(log["refresh"], log["steady"], per,
@@ -261,11 +296,13 @@ def main(argv=None, *, cell=None, require_tpu=True, wrap_step=None) -> int:
     from chipbench import spec
 
     cell = cell or spec.load_cell(args.workload)
+    phases = Phases()
     try:
         devices = devices_for(cell.chips, require_tpu)
     except NoChip as e:
         print(f"chipbench: {e}", file=sys.stderr)
         return 2
+    phases.mark("devices")  # JAX imported and its backend started
     if devices[0].platform != "cpu":
         enable_cache()
     from chipbench import check, reference
@@ -274,7 +311,7 @@ def main(argv=None, *, cell=None, require_tpu=True, wrap_step=None) -> int:
     if devices[0].platform == "tpu":
         peaks(devices[0].device_kind)  # an unknown chip is an error up front
     got = measure(cell, args.seed, args.seconds, bool(args.trace), devices,
-                  wrap_step=wrap_step)
+                  wrap_step=wrap_step, phases=phases)
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
               "count": len(devices), "memory_peak_bytes": got["peak_bytes"]}
     print(f"chipbench: memory_analysis {json.dumps(got['memory_analysis'])} "
@@ -294,7 +331,9 @@ def main(argv=None, *, cell=None, require_tpu=True, wrap_step=None) -> int:
                   "setup_s": got["setup_s"]}
         metrics = {k: {"value": values[k], "unit": units[k]} for k in units}
 
-    ref = reference.run(cell, got["abstract"], args.seed, devices=devices)
+    phases.mark("metrics")
+    ref = reference.run(cell, got["abstract"], args.seed, devices=devices, phases=phases)
+    print("chipbench: setup_phases " + json.dumps(phases.seconds), file=sys.stderr)
     read = check.readings(got["program"], ref)
     ok, table = check.verdict(read, cell.limits)
     ok = ok and got["log"]["failed"] == 0

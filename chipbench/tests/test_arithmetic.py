@@ -32,6 +32,8 @@ def test_kernel_flops_by_hand():
     assert work.ns_apply(2, 2, 3) == 72
     # L=1, m=2, n=4, r=1: 2*2*4*1 + 3*1*4 = 28
     assert work.lowrank_update(1, 2, 4, 1) == 28
+    # the projection alone: 2*2*4*1 = 16
+    assert work.project(1, 2, 4, 1) == 16
 
 
 def test_hbm_bytes_leave_out_on_chip_tensors():
@@ -105,3 +107,39 @@ def test_roofline_sums_least_time_over_kernel_time():
     # apply: 36 flops -> 0.36 s, no HBM bytes
     assert kernels.roofline(Run(), "newton_schulz") == pytest.approx(100 * 2.76 / 4.0)
     assert kernels.roofline(Run(), "lowrank_update") is None
+
+
+def test_projection_kernel_events_read_their_shapes():
+    ev = Event("%project_batched.3 = f32[2,128,2560]{2,1,0:T(8,128)S(1)} custom-call("
+               "f32[2,2560,128]{2,1,0:T(8,128)} %fusion.7, f32[2,2560,6912]{2,1,0:T(8,128)} "
+               "%fusion.8), custom_call_target=\"tpu_custom_call\", operand_layout_constraints="
+               "{f32[2,2560,128]{2,1,0}, f32[2,2560,6912]{2,1,0}}", 0.0, 1.0)
+    result, operands = kernels.tensors(ev)
+    assert result == [("f32", (2, 128, 2560), False)]
+    assert [s for _, s, _ in operands] == [(2, 2560, 128), (2, 2560, 6912)]
+    assert kernels.FLOPS["lowrank_update"]["project_batched"](
+        *(s for _, s, _ in operands)) == work.project(2, 2560, 6912, 128)
+
+
+def test_lowrank_roofline_reads_the_projection_kernel_alone():
+    """Where the projection runs ``project_batched`` and no fused update (each
+    chip's share of a member on a mesh), the metric reads that kernel."""
+    proj = Event("%project_batched.1 = f32[1,1,4]{2,1,0:S(1)} custom-call("
+                 "f32[1,2,1]{2,1,0} %p, f32[1,2,4]{2,1,0} %g)", 0.0, 8.0)
+    upd = Event("%lowrank_update_batched.2 = f32[1,1,4]{2,1,0:S(1)} custom-call("
+                "f32[1,2,1]{2,1,0:S(1)} %p, f32[1,2,4]{2,1,0:S(1)} %g, "
+                "f32[1,1,4]{2,1,0:S(1)} %r)", 8.0, 2.0)
+
+    class Run:
+        peak = PEAK
+
+        def __init__(self, ops):
+            self.ops = ops
+
+        def step_ops(self):
+            return self.ops
+
+    # project: 16 flops -> 0.16 s; HBM bytes p 8 + g 32 = 40 -> 4.0 s; over 8 s
+    assert kernels.roofline(Run([proj]), "lowrank_update") == pytest.approx(50.0)
+    # with the fused update: 28 flops -> 0.28 s, no HBM bytes; (4.0 + 0.28) / 10 s
+    assert kernels.roofline(Run([proj, upd]), "lowrank_update") == pytest.approx(42.8)

@@ -5,10 +5,11 @@ import jax.numpy as jnp
 import pytest
 
 from chipbench import check, inputs, reference, run
+from chipbench.optimizers import gum
 
 from . import tiny
 
-CELLS = {"dense": tiny.dense_cell}
+CELLS = {"dense": tiny.dense_cell, "dense-svd": tiny.dense_svd_cell}
 
 
 def program_readings(cell, seed):
@@ -51,3 +52,27 @@ def test_seed_wider_than_32_bits_changes_the_inputs():
     assert not bool(jnp.all(a == b))
     with pytest.raises(ValueError):
         inputs.seed_key(-1)
+
+
+@pytest.mark.parametrize("side, shape", [("left", (3, 24, 40)), ("right", (3, 40, 24))])
+def test_svd_projector_spans_the_leading_singular_subspace(side, shape):
+    """On stacked matrices with a clear gap after the rank, the reference's
+    projector spans the subspace of ``jnp.linalg.svd``'s leading singular
+    vectors on the matrix's short side."""
+    rank = 4
+    ku, kv, ks, kn = jax.random.split(jax.random.PRNGKey(7), 4)
+    L, m, n = shape
+    u = jnp.linalg.qr(jax.random.normal(ku, (L, m, m)))[0]
+    v = jnp.linalg.qr(jax.random.normal(kv, (L, n, n)))[0]
+    k = min(m, n)
+    s = jnp.concatenate([4.0 + jax.random.uniform(ks, (L, rank)),
+                         jax.random.uniform(kn, (L, k - rank))], axis=-1)
+    g = (u[..., :k] * s[:, None, :]) @ jnp.swapaxes(v[..., :k], -1, -2)
+    with jax.default_matmul_precision("highest"):
+        p = gum.projector("svd", g, rank, None, side)
+        short = g if side == "left" else jnp.swapaxes(g, -1, -2)
+        want = jnp.linalg.svd(short, full_matrices=False)[0][..., :rank]
+        outer = lambda q: q @ jnp.swapaxes(q, -1, -2)
+        gap = jnp.linalg.norm(outer(p) - outer(want), ord=2, axis=(-2, -1))
+    assert p.shape == (L, k, rank)
+    assert float(jnp.max(gap)) <= 1e-4, gap

@@ -4,6 +4,7 @@ import os
 import shutil
 import subprocess
 import sys
+import time
 
 import pytest
 
@@ -50,6 +51,34 @@ def test_sound_run_prints_the_contract_line(capsys):
     assert list(res)[-1] == "checks"
     assert res["device"]["window_s"] > 0 and "busy_s" in res["device"]
     assert {"refresh_stall_s", "opt_state_gib"} <= set(res["metrics"])
+
+
+def test_setup_compiles_once_and_drops_the_first_call(capsys, monkeypatch):
+    """Before the window the step is compiled once and called once, then
+    for the check's three steps and the warm-up; the first call (made slow
+    here) is not the refresh sample."""
+    from repro.train import Trainer
+
+    slow, lowered, calls = 2.0, [], []
+    lower_step = Trainer.lower_step
+    monkeypatch.setattr(Trainer, "lower_step",
+                        lambda self, *a, **k: lowered.append(1) or lower_step(self, *a, **k))
+
+    def counted(step):
+        def call(*args):
+            calls.append(1)
+            if len(calls) == 1:
+                time.sleep(slow)
+            return step(*args)
+        return call
+
+    cell = tiny.dense_cell()
+    assert run.main(tiny.run_args(trace=1), cell=cell, require_tpu=False,
+                    wrap_step=counted) == 0
+    res = tiny.result(capsys)
+    assert len(lowered) == 1
+    assert len(calls) - res["attempted"] == 1 + 3 + cell.traffic["warmup_steps"]
+    assert res["metrics"]["refresh_stall_s"]["value"] < slow / 2
 
 
 @pytest.mark.parametrize("fault", [tiny.state_unchanged, tiny.rows_of(2)],

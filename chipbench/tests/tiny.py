@@ -27,6 +27,13 @@ def dense_cell(dtype="bfloat16", d=128) -> spec.Cell:
     return _cell("qwen1.5-4b.finetune", dict(top, program=prog), 64, opt)
 
 
+def dense_svd_cell(dtype="bfloat16", d=128) -> spec.Cell:
+    """The dense cell with the SVD refresh and the paper's compensation."""
+    cell = dense_cell(dtype, d)
+    opt = dict(cell.traffic["optimizer"], projector="svd", compensation="paper")
+    return dataclasses.replace(cell, traffic=dict(cell.traffic, optimizer=opt))
+
+
 def sharded_cell(dtype="bfloat16", d=128, chips=4) -> spec.Cell:
     """The dense cell over a data mesh of ``chips`` devices, with the
     family-stacked GUM state sharded over it."""

@@ -32,6 +32,12 @@ def lowrank_update(L: int, m: int, n: int, r: int) -> int:
     return L * (2 * m * n * r + 3 * r * n)
 
 
+def project(L: int, m: int, n: int, r: int) -> int:
+    """Flops of ``PᵀG`` alone over L blocks, G (m, n) projected on its m
+    side: 2 m n r."""
+    return L * 2 * m * n * r
+
+
 def hbm_bytes(tensors) -> int:
     """Bytes of the (dtype, shape, in_hbm) tensors that live in HBM; a
     tensor the compiler placed in on-chip memory moves no HBM bytes."""
